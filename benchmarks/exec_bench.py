@@ -8,12 +8,13 @@
 ``exec_micro`` — one smoke network, run by the FAST CI tier;
                  ``benchmarks.run`` exits nonzero if the compiled engine is
                  not faster than the interpreter.
-``exec_sharded``       — mesh-aware engine (``compile_chain(mesh=...)``) on
-                 faked host devices, in a subprocess (the device count
-                 locks at first jax init): full zoo + LM blocks sharded-vs-
-                 single-device divergence, and 1-device vs N-fake-device
+``exec_sharded``       — mesh-aware engine (``compile_chain(mesh=...)``) in
+                 this process when it has the mesh's devices, else on
+                 faked CPU devices in a child (the device count locks at
+                 first jax init): full zoo + LM blocks sharded-vs-
+                 single-device divergence, and 1-device vs N-device
                  batched throughput scaling. Rides
-                 ``python -m repro.exec.shardcheck``.
+                 ``repro.exec.shardcheck.run``.
 ``exec_sharded_micro`` — FAST CI gate: one zoo net + the LM blocks + the
                  scaling bench; ``benchmarks.run`` exits nonzero when the
                  sharded program diverges (allclose, rtol 1e-4) or loses
@@ -21,10 +22,6 @@
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
 
 
@@ -126,30 +123,6 @@ def exec_micro():
 # ---------------------------------------------------------------------------
 # mesh-aware engine: sharded-vs-single-device + throughput scaling
 # ---------------------------------------------------------------------------
-def _run_shardcheck(args, mesh: str, timeout=1800):
-    """Spawn ``repro.exec.shardcheck`` with the mesh's device count faked
-    (multi-device CPU needs its own process: the count locks at the first
-    jax initialization, and this process already initialized)."""
-    from repro.shardpolicy import parse_mesh_spec
-
-    d, m = parse_mesh_spec(mesh)
-    devices = d * m
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count"
-                          f"={devices}")
-    proc = subprocess.run(
-        [sys.executable, "-m", "repro.exec.shardcheck", "--mesh", mesh,
-         *args],
-        capture_output=True, text=True, env=env, timeout=timeout)
-    if not proc.stdout.strip():
-        raise RuntimeError(f"shardcheck produced no output: "
-                           f"{proc.stderr[-2000:]}")
-    return json.loads(proc.stdout.strip().splitlines()[-1])
-
-
 def _sharded_summary(report):
     rows = report["rows"]
     errs = [r["max_err"] for r in rows if "max_err" in r]
@@ -169,14 +142,18 @@ def _sharded_summary(report):
 def exec_sharded(mesh: str = "4x2"):
     """Full sweep: all zoo nets + LM blocks sharded on faked devices, plus
     the data-parallel throughput-scaling bench (1 device vs all)."""
-    report = _run_shardcheck(["--nets", "all", "--lm", "--bench", "0"],
-                             mesh)
+    from repro.exec import shardcheck
+
+    report = shardcheck.run(["--mesh", mesh, "--nets", "all", "--lm",
+                             "--bench", "0"])
     return report["rows"], _sharded_summary(report)
 
 
 def exec_sharded_micro(mesh: str = "4x2"):
     """FAST-tier gate: one zoo net + the LM blocks + the scaling bench;
     nonzero exit from benchmarks.run on divergence or scaling <= 1."""
-    report = _run_shardcheck(["--nets", "MN", "--lm", "--bench", "0"],
-                             mesh)
+    from repro.exec import shardcheck
+
+    report = shardcheck.run(["--mesh", mesh, "--nets", "MN", "--lm",
+                             "--bench", "0"])
     return report["rows"], _sharded_summary(report)

@@ -57,6 +57,7 @@ def _run_cli(*extra):
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])
+    env["JAX_PLATFORMS"] = "cpu"        # host-only child: leave the chip
     proc = subprocess.run(
         [sys.executable, "-m", "repro.lint", "--scale", "reduced",
          "--format", "json", *extra],

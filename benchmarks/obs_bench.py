@@ -56,6 +56,7 @@ def _report_cli_ok(trace_path):
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env = dict(os.environ)
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"        # host-only child: leave the chip
     proc = subprocess.run(
         [sys.executable, "-m", "repro.obs.report", trace_path],
         capture_output=True, text=True, env=env, timeout=300)

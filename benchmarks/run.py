@@ -149,6 +149,8 @@ def main():
                          "(the devices are faked in a subprocess via "
                          "--xla_force_host_platform_device_count)")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.only:
         want = args.only.split(",")
         if args.engine == "sim" and set(want) != {"simval"}:

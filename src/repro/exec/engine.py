@@ -358,17 +358,20 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
     """
     import os
 
+    from .shardplan import plan_backend
+
     opts = CompileOptions(**options)
     chain.validate()
     fused, report, parts = partition_chain(chain, fuse=opts.fuse)
-    plan = plan_chain(fused, backend=opts.backend, mxu_min=opts.mxu_min,
+    backend = plan_backend(opts.backend, mesh)
+    plan = plan_chain(fused, backend=backend, mxu_min=opts.mxu_min,
                       segments=opts.segments)
     tune_report = None
     if opts.tune != "off":
         from .tune import tune_plan
         plan, tune_report = tune_plan(
             fused, plan, mode=opts.tune, db_path=opts.tune_db,
-            budget=opts.tune_budget, backend=opts.backend, tracer=tracer)
+            budget=opts.tune_budget, backend=backend, tracer=tracer)
     shard_plan = None
     if mesh is not None and not mesh.empty:
         from .shardplan import derive_plan

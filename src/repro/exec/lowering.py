@@ -233,11 +233,35 @@ def lower_conv(node: GConv, plan) -> Callable:
 
 
 def lower_conv_pallas(node: GConv, plan,
-                      block_o: int = 128) -> Optional[Callable]:
-    """NHWC Pallas spatial kernel for the plain 2-D case (groups=1, square
-    stride, symmetric padding); None when the geometry doesn't fit.
+                      block_o: Optional[int] = None) -> Optional[Callable]:
+    """NHWC Pallas spatial kernel for the plain 2-D case; None when the
+    geometry doesn't fit, and the caller dispatches to :func:`lower_conv`.
     ``block_o`` threads the tuner's output-channel block through to
-    ``gconv_spatial`` (the default matches the kernel's own)."""
+    ``gconv_spatial`` (None keeps the kernel's ``BLOCK_O``).
+
+    Eligibility, checked statically on every backend so the plan is the
+    same in interpret mode and on the chip:
+
+      * groups == 1, square stride and padding, symmetric padding (the
+        kernel's signature);
+      * ``kernels.gconv_spatial.mosaic_refusal`` is None. Each of its
+        rules is a refusal seen compiling for a TPU v5e:
+
+        - stride != 1 — full-width AlexNet conv1 (32x227x227x3, 11x11,
+          stride 4): "'vector.extract_strided_slice' op expected strides
+          to be confined to [1, 2)";
+        - block_o < O and not a multiple of 128 — e.g. the tuner's
+          block_o=64 at AN conv3 (O=384): "The Pallas TPU lowering
+          currently requires that the last two dimensions of your block
+          shape are divisible by 8 and 128 respectively";
+        - double-buffered blocks over ``VMEM_BLOCK_BUDGET`` — a
+          (32,112,112,64) 3x3 conv, and (32,80,80,64) 3x3: "Ran out of
+          memory in memory space vmem while allocating on stack".
+
+    The tuner asks this function per candidate ``block_o``, so its
+    candidates obey the same rule."""
+    from ..kernels.gconv_spatial import BLOCK_O, mosaic_refusal
+
     ch, windows, batch = plan
     dims = node.dims
     dch = dims[ch]
@@ -247,6 +271,10 @@ def lower_conv_pallas(node: GConv, plan,
     if (dh.stride, dh.pad) != (dw.stride, dw.pad):
         return None
     if dh.padr != dh.pad or dw.padr != dw.pad:
+        return None
+    block_o = BLOCK_O if block_o is None else block_o
+    if mosaic_refusal(dh.nips, dw.nips, dch.in_size, dh.nks, dw.nks, dch.nop,
+                      stride=dh.stride, pad=dh.pad, block_o=block_o):
         return None
 
     from ..kernels.gconv_spatial import gconv_spatial
@@ -368,7 +396,6 @@ def _tp_matmul(xb, kb, tp):
     (repro.exec.shardplan); an axis that doesn't divide never reaches
     here.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding as _NS, PartitionSpec as _P
 
     mesh, ax, mode, dp_g, dp_m = tp
@@ -387,8 +414,8 @@ def _tp_matmul(xb, kb, tp):
 
     xb = jax.lax.with_sharding_constraint(xb, _NS(mesh, x_spec))
     kb = jax.lax.with_sharding_constraint(kb, _NS(mesh, k_spec))
-    return shard_map(mm, mesh=mesh, in_specs=(x_spec, k_spec),
-                     out_specs=out_spec)(xb, kb)
+    return jax.shard_map(mm, mesh=mesh, in_specs=(x_spec, k_spec),
+                         out_specs=out_spec)(xb, kb)
 
 
 def lower_grouped_matmul(node: GConv, plan, *, pallas: bool = False,

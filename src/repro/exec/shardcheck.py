@@ -8,9 +8,12 @@ requested program twice — single-device and against a mesh — and compare.
         PYTHONPATH=src python -m repro.exec.shardcheck \\
         --mesh 8x1 --nets MN --lm --serve --bench 0
 
-Run WITHOUT enough devices, the driver re-execs itself in a subprocess
-with the fake-device flag set (the device count locks at the first jax
-initialization, so it cannot be raised in-process).
+With enough devices the checks run in this process (one process per
+chip: a child could not open devices its parent holds). On a CPU backend
+with too few devices, :func:`run` re-execs in a subprocess with the
+fake-device flag and ``JAX_PLATFORMS=cpu`` (the device count locks at the
+first jax initialization, so it cannot be raised in-process); on an
+accelerator with too few devices it fails.
 
 Checks (each a row in the JSON report printed as the last stdout line):
 
@@ -47,13 +50,19 @@ def _mesh_devices(spec: str) -> int:
     return d * m
 
 
-def _reexec(argv, devices: int) -> int:
+def _reexec_on_fake_cpus(argv, devices: int) -> dict:
     env = dict(os.environ)
+    src = os.path.join(os.path.dirname(__file__), "..", "..")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={devices}")
     proc = subprocess.run([sys.executable, "-m", "repro.exec.shardcheck",
-                           *argv], env=env)
-    return proc.returncode
+                           *argv], capture_output=True, text=True, env=env)
+    if not proc.stdout.strip():
+        raise RuntimeError(f"shardcheck produced no output: "
+                           f"{proc.stderr[-2000:]}")
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def _tiny_cfg(**kw):
@@ -179,9 +188,10 @@ def bench_scaling(iters=3):
 
     from repro.core.interpreter import ChainExecutor
     from repro.exec import compile_chain
+    from repro.launch.mesh import make_debug_mesh
     from repro.models import cnn, lm_chain
 
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = make_debug_mesh(len(jax.devices()), 1)
 
     cfg = _tiny_cfg(d_model=BENCH_D_MODEL, n_heads=4, n_kv_heads=4,
                     d_ff=2 * BENCH_D_MODEL, vocab=256)
@@ -223,7 +233,7 @@ def bench_scaling(iters=3):
             "ok": bool(scaling > 1.0)}
 
 
-def main(argv=None):
+def _parse(argv):
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="8x1", help="'D' or 'DxM'")
     ap.add_argument("--nets", default="",
@@ -234,14 +244,11 @@ def main(argv=None):
                     help="check staggered DP serving vs sequential")
     ap.add_argument("--bench", type=int, default=-1, metavar="ITERS",
                     help="scaling bench iters (0 = default 3, -1 = skip)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    need = _mesh_devices(args.mesh)
-    import jax                       # first init locks the device count
 
-    if len(jax.devices()) < need:
-        raise SystemExit(_reexec(sys.argv[1:] if argv is None else argv,
-                                 need))
+def _run_here(args) -> dict:
+    import jax
 
     from repro.launch.mesh import mesh_from_spec
     from repro.models import cnn
@@ -259,8 +266,28 @@ def main(argv=None):
         rows.append(check_serve(mesh))
     if args.bench >= 0:
         rows.append(bench_scaling(iters=args.bench or 3))
-    report = {"mesh": args.mesh, "devices": len(jax.devices()),
-              "rows": rows, "ok": bool(rows) and all(r["ok"] for r in rows)}
+    return {"mesh": args.mesh, "devices": len(jax.devices()),
+            "rows": rows, "ok": bool(rows) and all(r["ok"] for r in rows)}
+
+
+def run(argv) -> dict:
+    """The checks' JSON report: computed in this process when it has the
+    mesh's devices, else on faked CPU devices in a child (CPU only)."""
+    args = _parse(argv)
+    need = _mesh_devices(args.mesh)
+    import jax                       # first init locks the device count
+
+    have = len(jax.devices())
+    if have >= need:
+        return _run_here(args)
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(f"--mesh {args.mesh} needs {need} devices; this "
+                           f"{jax.default_backend()} host has {have}")
+    return _reexec_on_fake_cpus(argv, need)
+
+
+def main(argv=None):
+    report = run(sys.argv[1:] if argv is None else argv)
     print(json.dumps(report))
     raise SystemExit(0 if report["ok"] else 1)
 

@@ -85,6 +85,19 @@ class ShardPlan:
         return "\n".join(lines)
 
 
+def plan_backend(backend: str, mesh) -> str:
+    """The dispatch backend a chain compiled against ``mesh`` plans with.
+
+    GSPMD cannot partition a Mosaic kernel: compiling one into a program
+    sharded over several TPU devices raises "Mosaic kernels cannot be
+    automatically partitioned". So ``auto`` on a multi-device mesh plans
+    XLA's own lowerings (``matmul:jnp``, ``conv:lax``, jnp segments), which
+    GSPMD partitions. An explicit ``"pallas"`` is kept as asked."""
+    if backend == "auto" and mesh is not None and mesh.size > 1:
+        return "jnp"
+    return backend
+
+
 def _matmul_geometry(node: GConv, chain: Chain):
     """(match plan, G, M, N, K) of a grouped-matmul node, or None."""
     if node.kernel is None:
@@ -112,8 +125,8 @@ def derive_plan(chain: Chain, dispatch: Dict[str, str], mesh: Mesh) \
 
     ``dispatch`` is the compiled plan's node -> backend-tag table; only
     ``matmul:jnp`` nodes are candidates for the explicit tensor-parallel
-    split (the Pallas path keeps its single-device kernel; GSPMD may still
-    shard it).
+    split. A multi-device plan holds no Pallas step under ``auto`` (see
+    :func:`plan_backend`).
     """
     dp = policy.dp_axes(mesh)
     tp = "model" if "model" in mesh.axis_names else None

@@ -315,11 +315,12 @@ class _Group:
                 cands.append(("einsum", None))
         elif self.cplan is not None:
             cands.append(("conv:lax", None))
-            if (self.pallas_ok
-                    and low.lower_conv_pallas(self.node, self.cplan)
-                    is not None):
+            if self.pallas_ok:
                 cands += [("conv:pallas", b)
-                          for b in _conv_blocks(self.geometry[0])]
+                          for b in _conv_blocks(self.geometry[0])
+                          if low.lower_conv_pallas(self.node, self.cplan,
+                                                   block_o=b["o"])
+                          is not None]
             if self.einsum_ok:
                 cands.append(("einsum", None))
         # heuristic first: measured ties resolve to the incumbent
@@ -350,11 +351,14 @@ class _Group:
         if tag == "conv:lax":
             return self.cplan is not None and block is None
         if tag == "conv:pallas":
-            if (self.cplan is None or not self.pallas_ok
-                    or low.lower_conv_pallas(self.node, self.cplan) is None):
+            if self.cplan is None or not self.pallas_ok:
                 return False
-            return (block is None
-                    or (sorted(block) == ["o"] and 1 <= block["o"]))
+            if block is not None and not (sorted(block) == ["o"]
+                                          and 1 <= block["o"]):
+                return False
+            return low.lower_conv_pallas(
+                self.node, self.cplan,
+                block_o=block["o"] if block else None) is not None
         if tag == "einsum":
             return self.einsum_ok and block is None
         return False
@@ -370,7 +374,7 @@ class _Group:
             return low.lower_conv(self.node, self.cplan)
         if tag == "conv:pallas":
             fn = low.lower_conv_pallas(self.node, self.cplan,
-                                       block_o=block["o"] if block else 128)
+                                       block_o=block["o"] if block else None)
             assert fn is not None, "conv:pallas candidate without geometry"
             return fn
         if tag == "einsum":

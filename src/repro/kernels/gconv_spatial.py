@@ -12,20 +12,65 @@ Blocking: grid (B, O-tiles). Each step holds one padded input image
 (OH, OW, bo) output block. The static KH x KW Python loop unrolls into
 MXU dots over the same VMEM block — this is the Eyeriss overlap-reuse
 primitive (paper Fig. 8) re-derived for a vector/matrix memory hierarchy.
-For feature maps too large for VMEM the chain mapper splits H into
-halo-overlapped tiles before lowering (see core.mapping); benchmark-scale
-CNNs fit comfortably (<= 16 MB).
+
+Nothing splits a feature map into halo tiles: the whole padded image is one
+block, so Mosaic compiles the kernel only for stride 1 and for geometries
+whose blocks fit :data:`VMEM_BLOCK_BUDGET`. :func:`mosaic_refusal` states
+that rule; ``exec.lowering.lower_conv_pallas`` applies it on every backend
+(interpret mode included, so CPU and TPU plans agree) and sends refused
+convs to ``lax.conv_general_dilated``.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import cdiv, use_interpret
+from .common import cdiv, round_up, use_interpret
+
+BLOCK_O = 128                   # default output-channel block
+O_ALIGN = 128                   # a block narrower than O must fill lanes
+# Double-buffered f32 input + weight + output blocks, each padded to the
+# (8, 128) tile. Compiling for a TPU v5e, every zoo geometry at or under
+# 11 MiB by this count compiled, and the smallest refusal
+# ("Ran out of memory in memory space vmem") counted 11.4 MiB.
+VMEM_BLOCK_BUDGET = 8 * 2**20
+
+
+def vmem_block_bytes(hp: int, wp: int, c: int, kh: int, kw: int,
+                     oh: int, ow: int, bo: int) -> int:
+    """VMEM bytes of the kernel's double-buffered blocks: one padded image
+    (hp, wp, c), one weight slice (kh, kw, c, bo), one output block
+    (oh, ow, bo), all f32 and padded to the (8, 128) sublane x lane tile."""
+    def tile(*shape):
+        return (4 * math.prod(shape[:-2]) * round_up(shape[-2], 8)
+                * round_up(shape[-1], 128))
+
+    return 2 * (tile(hp, wp, c) + tile(kh, kw, c, bo) + tile(oh, ow, bo))
+
+
+def mosaic_refusal(h: int, w: int, c: int, kh: int, kw: int, o: int, *,
+                   stride: int, pad: int,
+                   block_o: int = BLOCK_O) -> Optional[str]:
+    """Why Mosaic refuses ``gconv_spatial`` at this geometry, or None when
+    it compiles. Each rule is a refusal seen compiling for a TPU v5e."""
+    if stride != 1:
+        return (f"stride {stride}: strided window slices are refused "
+                f"('vector.extract_strided_slice' strides must be 1)")
+    bo = min(block_o, o)
+    if bo != o and bo % O_ALIGN:
+        return (f"block_o {bo} < O={o} is not a multiple of {O_ALIGN} "
+                f"(block shape must be (8, 128)-divisible or whole)")
+    hp, wp = h + 2 * pad, w + 2 * pad
+    need = vmem_block_bytes(hp, wp, c, kh, kw, hp - kh + 1, wp - kw + 1, bo)
+    if need > VMEM_BLOCK_BUDGET:
+        return (f"blocks need {need} B of VMEM > budget "
+                f"{VMEM_BLOCK_BUDGET} B (whole padded image per block)")
+    return None
 
 
 def _kernel(x_ref, w_ref, o_ref, *, kh: int, kw: int, stride: int,
@@ -48,7 +93,7 @@ def _kernel(x_ref, w_ref, o_ref, *, kh: int, kw: int, stride: int,
 
 
 def gconv_spatial(x: jax.Array, w: jax.Array, *, stride: int = 1,
-                  pad: int = 0, block_o: int = 128,
+                  pad: int = 0, block_o: int = BLOCK_O,
                   interpret: Optional[bool] = None) -> jax.Array:
     """NHWC conv: x (B, H, W, C), w (KH, KW, C, O) -> (B, OH, OW, O) f32.
 

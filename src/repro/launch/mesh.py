@@ -17,15 +17,23 @@ from repro.shardpolicy import dp_axes  # noqa: F401  (re-export: the policy
 from repro.shardpolicy import parse_mesh_spec
 
 
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules here
+    place arrays with ``with_sharding_constraint`` and let GSPMD propagate,
+    which ``Explicit`` axes (the JAX default since 0.7) refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 1, n_model: int = 1):
     """Tiny mesh over however many (virtual) devices a test asked for."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def mesh_from_spec(spec: str):

@@ -135,6 +135,8 @@ def main():
                          "must recover via checkpoints, so --ckpt-dir is "
                          "required")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     fault_hook = None
     if args.inject_fault:
         if not args.ckpt_dir:

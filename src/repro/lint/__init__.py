@@ -85,9 +85,10 @@ def build_context(chain, *, backend: str = "auto", mxu_min: int = 128,
     engine — ``mesh`` may be a :class:`FakeMesh`."""
     from ..exec.dispatch import plan_chain
     from ..exec.partition import partition_chain
+    from ..exec.shardplan import plan_backend
     fused, report, parts = partition_chain(chain, fuse=fuse)
-    plan = plan_chain(fused, backend=backend, mxu_min=mxu_min,
-                      segments=segments)
+    plan = plan_chain(fused, backend=plan_backend(backend, mesh),
+                      mxu_min=mxu_min, segments=segments)
     for host, members in report.groups.items():
         for m in members:
             plan.dispatch.setdefault(m, f"fused:{host}")

@@ -100,7 +100,7 @@ def test_plan_input_specs_guarded():
     for name, spec in plan.in_specs.items():
         shape = eng.chain.inputs[name].shape
         if shape and shape[0] % 2 == 0:
-            assert spec[0] == ("data",), name
+            assert spec[0] == "data", name
         else:
             assert tuple(spec) == (None,) * len(spec), name
 

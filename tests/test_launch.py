@@ -95,6 +95,31 @@ def test_serve_continuous_batching():
     assert all(len(o) == 4 for o in outs)
 
 
+def test_compile_cache_keeps_the_env_dir(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it; the helper reports
+    it and places no other directory."""
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == was
+
+
+def test_compile_cache_defaults_to_the_repo(monkeypatch):
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    try:
+        got = enable_compile_cache()
+        assert got == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 def test_collective_parser():
     from repro.analysis.roofline import collective_bytes
 
