@@ -13,7 +13,7 @@ all of which must hold for ``ok``:
     trace just written.
   * **disabled overhead** — the exec micro cell (zoo net ``MN``, batch 1)
     run on a plain engine vs an engine built with ``profile=True`` but a
-    *disabled* tracer: the latter walks the full profiling code path and
+    *disabled* tracer: the latter checks its tracer on every call and
     must cost no more than ``MAX_DISABLED_OVERHEAD`` extra (interleaved
     min-of-repeats timing, so machine noise cancels).
 """

@@ -33,14 +33,20 @@ engine on faked host devices (tests/test_exec_sharded.py).
 """
 from __future__ import annotations
 
+import re
+import time
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 from typing import Dict, List, Mapping, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..core.chain import Chain
 from ..core.fusion import ExecGroup, FusionReport
+from ..obs import compiles
+from ..obs.metrics import Metrics
 from .batch import BucketedCache, batch_bucket, pad_leading, unpad_leading
 from .dispatch import Plan, plan_chain
 from .partition import partition_chain
@@ -53,8 +59,8 @@ class CompileOptions:
     backend: str = "auto"        # auto | jnp | pallas
     mxu_min: int = 128           # min K/N to prefer the Pallas matmul (auto)
     jit: bool = True
-    profile: bool = False        # per-step timed spans into a repro.obs
-                                 # tracer (see CompiledChain docstring)
+    profile: bool = False        # record the engine's spans into a
+                                 # repro.obs tracer (see compile_chain)
     lint: Optional[str] = None   # off|info|warn|error: run the repro.lint
                                  # passes post-compile and raise LintError
                                  # at/above that severity. None reads the
@@ -100,16 +106,12 @@ class CompiledChain:
         # leading-batch execution: one vmapped program per (keep_all,
         # batch bucket), cached per engine (exec.batch.BucketedCache)
         self._batched = BucketedCache(self._build_batched)
-        # profiling (repro.obs): per-step jitted programs so each fusion-
-        # group step can be timed device-synced. The DISABLED path costs
-        # exactly one flag check in __call__ — no tracer object, span or
-        # dict is ever allocated unless profiling is live.
-        self._profile = options.profile
-        self.tracer = None
-        if options.profile:
-            from ..obs.trace import Tracer
-            self.tracer = tracer if tracer is not None else Tracer()
-            self._step_fns: Dict[str, object] = {}
+        # observability (repro.obs): the spans of __call__ go into
+        # ``tracer`` while it is enabled; ``metrics`` holds each program's
+        # build counters and the phase seconds of timed calls
+        self.tracer = tracer
+        self.metrics = Metrics()
+        self._builds = compiles.install()
 
     # -- parameter init (the oracle's own recipe, shared) ---------------
     def init_params(self, key, scale: float = 0.1) -> Dict[str, jnp.ndarray]:
@@ -126,7 +128,8 @@ class CompiledChain:
         env: Dict[str, jnp.ndarray] = dict(inputs)
         env.update(params)
         for step in (self.steps if steps is None else steps):
-            env[step.name] = step.run(env)
+            with jax.named_scope(step.name):
+                env[step.name] = step.run(env)
         if keep_all:
             return env
         outs = self.chain.outputs or [list(self.chain.nodes)[-1]]
@@ -191,63 +194,9 @@ class CompiledChain:
                 f"inconsistent leading batch sizes {sorted(sizes)}")
         return sizes.pop()
 
-    # -- profiled execution (repro.obs) ---------------------------------
-    def _step_fn(self, step):
-        """Per-step jitted program (profile mode runs steps one by one so
-        each can be block_until_ready-timed; the single fused program of
-        the fast path cannot attribute time to its interior)."""
-        fn = self._step_fns.get(step.name)
-        if fn is None:
-            run = step.run
-            fn = jax.jit(run) if self.options.jit else run
-            self._step_fns[step.name] = fn
-        return fn
-
-    def _profiled(self, ins, ps, keep_all):
-        """Exact-shape execution with one device-synced span per fusion-
-        group step, attributed with the step's backend tag and the plan
-        signature. The first run of a step is recorded under cat
-        ``compile`` (trace + XLA compile + execute), steady-state runs
-        under cat ``execute`` — so compile time never pollutes the
-        execute-time attribution. The loop keeps only two clock reads of
-        bookkeeping per step and defers event construction until after
-        the enclosing chain span closes, so >= 95% of the chain span's
-        wall time is attributed to named steps (the report CLI's
-        ``profile.coverage``)."""
-        import time as _time
-
-        tr = self.tracer
-        sig = self._plan.signature
-        env: Dict[str, jnp.ndarray] = dict(ins)
-        env.update(ps)
-        steps = self._steps_sharded
-        step_fns = self._step_fns
-        marks = []
-        with tr.span(f"chain:{self.chain.name}", cat="chain",
-                     attrs={"signature": sig,
-                            "steps": len(steps)}) as chain_span:
-            for step in steps:
-                compiled = step.name in step_fns
-                fn = step_fns[step.name] if compiled else self._step_fn(step)
-                t0 = _time.perf_counter()
-                out = jax.block_until_ready(fn(env))
-                t1 = _time.perf_counter()
-                env[step.name] = out
-                marks.append((step, compiled, t0, t1))
-        parent = getattr(chain_span, "id", None)
-        for step, compiled, t0, t1 in marks:
-            tr.add_span(step.name, "execute" if compiled else "compile",
-                        t0, t1, parent=parent,
-                        attrs={"backend": step.backend, "signature": sig})
-        if keep_all:
-            return env
-        outs = self.chain.outputs or [list(self.chain.nodes)[-1]]
-        return {o: env[o] for o in outs}
-
-    def __call__(self,
-                 inputs: Mapping[str, jnp.ndarray],
-                 params: Optional[Mapping[str, jnp.ndarray]] = None,
-                 keep_all: bool = False) -> Dict[str, jnp.ndarray]:
+    def _args(self, inputs, params):
+        """The chain's inputs and parameters as arrays, and the leading
+        batch size (None for exact shapes)."""
         params = params or {}
         ins = {}
         for name in self.chain.inputs:
@@ -259,27 +208,99 @@ class CompiledChain:
             if name not in params:
                 raise ValueError(f"missing chain param {name!r}")
             ps[name] = jnp.asarray(params[name])
-        n = self._batch_size(ins)
-        profiling = self._profile and self.tracer.enabled
+        return ins, ps, self._batch_size(ins)
+
+    def _launch(self, ins, ps, n, keep_all):
+        """Run the program (exact-shape, or the batch bucket's) up to its
+        unfinished outputs; returns them and what the call built, if it
+        built anything (a :class:`repro.obs.compiles.BuildTotals`)."""
+        builds = self._builds
         if n is None:
-            if profiling:
-                return self._profiled(ins, ps, keep_all)
-            return dict(self._fn(keep_all)(ins, ps))
-        bucket = batch_bucket(n, self._min_bucket)
-        if profiling:
-            # batched programs are one fused vmap: attribute the call as a
-            # whole (per-step attribution is an exact-shape-mode feature)
-            with self.tracer.span(f"batched:{self.chain.name}", cat="chain",
-                                  attrs={"backend": "batched", "n": n,
-                                         "bucket": bucket,
-                                         "signature":
-                                             self._plan.signature}):
-                fn = self._batched.get((keep_all, bucket))
-                out = jax.block_until_ready(fn(pad_leading(ins, bucket), ps))
-            return dict(unpad_leading(out, n))
-        fn = self._batched.get((keep_all, bucket))
-        out = fn(pad_leading(ins, bucket), ps)
-        return dict(unpad_leading(out, n))
+            fn, bucket = self._fn(keep_all), None
+        else:
+            bucket = batch_bucket(n, self._min_bucket)
+            fn = self._batched.get((keep_all, bucket))
+            ins = pad_leading(ins, bucket)
+        before = builds.totals
+        out = fn(ins, ps)
+        built = None
+        if builds.totals is not before:
+            built = builds.totals - before
+            self._record_build(keep_all, bucket, built)
+        out = dict(out) if n is None else dict(unpad_leading(out, n))
+        return out, built
+
+    def _record_build(self, keep_all, bucket, built):
+        program = ("exact" if bucket is None else f"bucket={bucket}") \
+            + ("+all" if keep_all else "")
+        m = self.metrics
+        m.counter("engine_programs_compiled", program=program).inc(
+            built.programs)
+        m.counter("engine_compile_cache_hits", program=program).inc(
+            built.cache_hits)
+        for name, secs in (("engine_trace_s", built.trace_s),
+                           ("engine_compile_s", built.compile_s)):
+            g = m.gauge(name, program=program)
+            g.set(g.value + secs)
+
+    def __call__(self,
+                 inputs: Mapping[str, jnp.ndarray],
+                 params: Optional[Mapping[str, jnp.ndarray]] = None,
+                 keep_all: bool = False) -> Dict[str, jnp.ndarray]:
+        tr = self.tracer
+        if (tr is not None and tr.enabled) or TraceAnnotation.is_enabled():
+            return self._timed_call(inputs, params, keep_all)
+        # nothing records: annotations would be dropped, so none are made
+        ins, ps, n = self._args(inputs, params)
+        return self._launch(ins, ps, n, keep_all)[0]
+
+    def _timed_call(self, inputs, params, keep_all):
+        """``__call__`` while a profiler session or the tracer records:
+        annotations ``engine.call`` > ``engine.args`` / ``engine.launch``,
+        the phases' seconds summed into ``metrics`` (``engine_span_s`` by
+        span, ``engine_timed_calls``) and, with an enabled tracer, the same
+        spans (and ``engine.compile`` under a launch that built its
+        program) in its ring."""
+        t0 = time.perf_counter()
+        with TraceAnnotation("engine.call"):
+            with TraceAnnotation("engine.args"):
+                ins, ps, n = self._args(inputs, params)
+            t1 = time.perf_counter()
+            with TraceAnnotation("engine.launch"):
+                out, built = self._launch(ins, ps, n, keep_all)
+            t2 = time.perf_counter()
+        m = self.metrics
+        m.counter("engine_timed_calls").inc()
+        m.counter("engine_span_s", span="engine.args").inc(t1 - t0)
+        m.counter("engine_span_s", span="engine.launch").inc(t2 - t1)
+        tr = self.tracer
+        if tr is not None and tr.enabled:
+            call = tr.add_span("engine.call", "engine", t0, t2,
+                               attrs={"signature": self.signature,
+                                      "n": n})
+            tr.add_span("engine.args", "engine", t0, t1, parent=call)
+            launch = tr.add_span("engine.launch", "engine", t1, t2,
+                                 parent=call)
+            if built is not None:
+                tr.add_span("engine.compile", "compile", t1, t2,
+                            parent=launch, attrs=built._asdict())
+        return out
+
+    def op_steps(self) -> Dict[str, str]:
+        """``{HLO instruction: step name}`` of the exact-shape program, as
+        compiled for the default device: the instructions a profiler trace
+        names, each put down to the fusion-group step whose
+        ``jax.named_scope`` its ``metadata`` carries. XLA's layout copies
+        carry no metadata; each takes the step of its first user that has
+        one. Instructions outside every step scope are left out."""
+        def spec(infos):
+            return {n: jax.ShapeDtypeStruct(i.shape, jnp.dtype(i.dtype))
+                    for n, i in infos.items()}
+
+        text = self._fn(False).lower(
+            spec(self.chain.inputs), spec(self.chain.params)
+        ).compile().as_text()
+        return hlo_op_steps(text, [s.name for s in self.steps])
 
     # -- batched-mode introspection -------------------------------------
     @property
@@ -324,6 +345,67 @@ class CompiledChain:
         return "\n".join(lines)
 
 
+_HLO_INST = re.compile(r"^\s*(?:ROOT )?%([^\s=]+) = ")
+_HLO_REF = re.compile(r"%([^\s,(){}=]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLEE = re.compile(r"\b(?:calls|to_apply)=%([^\s,(){}]+)")
+
+
+def hlo_op_steps(text: str, steps) -> Dict[str, str]:
+    """``{instruction: step}`` of a compiled HLO module's text (see
+    :meth:`CompiledChain.op_steps`): an instruction's step is the first
+    scope of its ``op_name`` (the last part is the operation) that names
+    one of ``steps``; one without takes its first user's. Instructions of
+    fusion bodies and reduction regions, which no trace names, are left
+    out."""
+    steps = set(steps)
+    comps: List[List[tuple]] = []
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[1] if line.startswith("ENTRY") \
+                else line.split()[0]
+            comps.append([name.lstrip("%")])
+            continue
+        m = _HLO_INST.match(line)
+        if m and comps:
+            comps[-1].append((m.group(1), line))
+    callees = {c for comp in comps for _n, line in comp[1:]
+               for c in _HLO_CALLEE.findall(line)}
+    out: Dict[str, str] = {}
+    for comp in comps:
+        if comp[0] in callees:
+            continue
+        insts = comp[1:]
+        users: Dict[str, List[str]] = {}
+        own: Dict[str, Optional[str]] = {}
+        for name, line in insts:
+            body = line.split(" = ", 1)[1]
+            for ref in _HLO_REF.findall(body):
+                users.setdefault(ref, []).append(name)
+            m = _HLO_OP_NAME.search(line)
+            scopes = m.group(1).split("/")[:-1] if m else ()
+            own[name] = next((x for x in scopes if x in steps), None)
+        for name, line in reversed(insts):
+            step = own[name]
+            if step is None and " parameter(" not in line:
+                step = next((own[u] for u in users.get(name, ())
+                             if own.get(u) is not None), None)
+                own[name] = step
+            if step is not None:
+                out[name] = step
+    return out
+
+
+@contextmanager
+def _phase(name: str, tracer):
+    """One set-up phase of ``compile_chain``: a profiler annotation, and a
+    span in ``tracer`` unless it is None."""
+    span = tracer.span(name, cat="compile") if tracer is not None \
+        else nullcontext()
+    with TraceAnnotation(name), span:
+        yield
+
+
 def compile_chain(chain: Chain, mesh=None, tracer=None,
                   **options) -> CompiledChain:
     """Compile a chain for execution. See :class:`CompileOptions`.
@@ -331,13 +413,29 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
     ``mesh``: a ``jax.sharding.Mesh`` to compile a SHARDED program against
     (see the module docstring); ``None`` keeps the single-device engine.
 
-    ``profile=True``: wrap each fusion-group step in a device-synced timed
-    span recorded into ``engine.tracer`` (a fresh ``repro.obs.trace.
-    Tracer`` unless ``tracer=`` is given) — backend + plan-signature
-    attributed, compile events separate from execute events; export with
-    ``engine.tracer.write(path)`` and summarize with ``python -m
-    repro.obs.report``. With the default ``profile=False`` the hot path
-    is untouched beyond one flag check per call.
+    Tracing (``repro.obs``): every call runs the one fused program, with
+    one ``jax.named_scope`` per fusion-group step (``engine.op_steps()``
+    maps the compiled program's HLO instructions to steps), inside the
+    host spans ``engine.call`` > ``engine.args`` (input checks and
+    ``jnp.asarray``) / ``engine.launch`` (the jitted program, until it
+    returns its unfinished outputs), written as ``jax.profiler``
+    annotations while a profiler session records, so that they land in its
+    trace beside the device's operations. A call that builds
+    a program counts it in ``engine.metrics`` under its program key:
+    ``engine_programs_compiled``, ``engine_compile_cache_hits`` and the
+    seconds ``engine_trace_s`` (tracing and lowering) and
+    ``engine_compile_s`` (XLA/Mosaic compile or persistent-cache load).
+    ``compile_chain``'s own phases are annotated ``compile.partition``,
+    ``compile.plan``, ``compile.tune`` and ``compile.lint``.
+
+    ``profile=True`` (or an enabled ``tracer=``) also records those spans
+    into ``engine.tracer`` (a fresh ``repro.obs.trace.Tracer`` unless
+    ``tracer=`` is given), and an ``engine.compile`` span under the launch
+    that built a program; export with ``engine.tracer.write(path)`` and
+    summarize with ``python -m repro.obs.report``. While a profiler
+    session or the tracer records, each call's phase seconds are also
+    summed into ``engine.metrics`` (``engine_span_s`` by ``span``, over
+    ``engine_timed_calls``). Without either, a call pays two flag checks.
 
     ``lint="error"``: run the `repro.lint` static passes over the compiled
     artifacts (chain + plan + shard plan) and raise
@@ -361,17 +459,24 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
     from .shardplan import plan_backend
 
     opts = CompileOptions(**options)
+    if opts.profile and tracer is None:
+        from ..obs.trace import Tracer
+        tracer = Tracer()
+    spans = tracer if tracer is not None and tracer.enabled else None
     chain.validate()
-    fused, report, parts = partition_chain(chain, fuse=opts.fuse)
+    with _phase("compile.partition", spans):
+        fused, report, parts = partition_chain(chain, fuse=opts.fuse)
     backend = plan_backend(opts.backend, mesh)
-    plan = plan_chain(fused, backend=backend, mxu_min=opts.mxu_min,
-                      segments=opts.segments)
+    with _phase("compile.plan", spans):
+        plan = plan_chain(fused, backend=backend, mxu_min=opts.mxu_min,
+                          segments=opts.segments)
     tune_report = None
     if opts.tune != "off":
         from .tune import tune_plan
-        plan, tune_report = tune_plan(
-            fused, plan, mode=opts.tune, db_path=opts.tune_db,
-            budget=opts.tune_budget, backend=backend, tracer=tracer)
+        with _phase("compile.tune", spans):
+            plan, tune_report = tune_plan(
+                fused, plan, mode=opts.tune, db_path=opts.tune_db,
+                budget=opts.tune_budget, backend=backend, tracer=tracer)
     shard_plan = None
     if mesh is not None and not mesh.empty:
         from .shardplan import derive_plan
@@ -388,7 +493,8 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
         else os.environ.get("REPRO_LINT", "off")
     if level and level != "off":
         from ..lint import LintError, lint_compiled
-        eng.lint_report = lint_compiled(eng)
+        with _phase("compile.lint", spans):
+            eng.lint_report = lint_compiled(eng)
         if eng.lint_report.at_least(level):
             raise LintError(eng.lint_report, level)
     return eng

@@ -1,15 +1,21 @@
 """``repro.obs`` — unified tracing, metrics and profiling substrate.
 
 Dependency-free (stdlib only at import time) so every layer can emit
-through it: the compiled engine's ``profile=True`` mode, the serving
-driver's ``--trace`` request-lifecycle trace, the simulator's stats and
-the benchmark harness's provenance-stamped artifacts.
+through it: the compiled engine's spans and build counters (its
+``profile=True`` mode records the spans ``engine.call`` > ``engine.args``
+/ ``engine.launch`` > ``engine.compile`` of each call of the one fused
+program, and ``compile.*`` of ``compile_chain``, into a tracer), the
+serving driver's ``--trace`` request-lifecycle trace, the simulator's
+stats and the benchmark harness's provenance-stamped artifacts.
 
   * :mod:`repro.obs.trace`   — ring-buffered span tracer, Chrome/JSONL
     export (:data:`~repro.obs.trace.SCHEMA_VERSION`), :func:`load_trace`.
   * :mod:`repro.obs.metrics` — labeled counters/gauges/histograms with
     ``snapshot``/``merge``/``diff`` and one versioned ``to_dict`` schema;
     the shared :func:`~repro.obs.metrics.percentile`.
+  * :mod:`repro.obs.compiles` — the process's JAX program builds (trace
+    and compile seconds, programs, persistent-cache hits) from one
+    ``jax.monitoring`` listener; imports JAX only when installed.
   * :mod:`repro.obs.report`  — ``python -m repro.obs.report TRACE``
     (top spans by self-time, backend time share, slot utilization,
     request-latency breakdown, profile coverage).

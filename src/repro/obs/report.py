@@ -4,9 +4,8 @@ Reconstructs, from any trace written by :class:`repro.obs.trace.Tracer`:
 
   * **top spans by self-time** — per span name, call count, total and
     self time (duration minus nested children), the profiler's headline;
-  * **per-backend time share** — execute spans attributed with a
-    ``backend`` arg (the profiled compiled engine) aggregated into a
-    time-share map;
+  * **per-backend time share** — spans attributed with a ``backend``
+    arg aggregated into a time-share map;
   * **request latency breakdown** — ``request``-category lifecycle spans:
     request count plus queue-wait/TTFT/latency p50/p99 recomputed from
     the per-request args through the same :func:`repro.obs.metrics.
@@ -15,8 +14,8 @@ Reconstructs, from any trace written by :class:`repro.obs.trace.Tracer`:
   * **slot utilization** — the serving driver's per-tick ``slots``
     counter track averaged against the slot capacity in the trace meta;
   * **profile coverage** — for profiled engine runs, the fraction of the
-    latest ``chain`` span's wall time attributed to named child steps
-    (the acceptance bar is >= 0.95);
+    latest ``engine.call`` span's wall time covered by its child spans
+    (``engine.args``, ``engine.launch``);
   * **fault timeline** — ``chaos``/``resilience``-category instants
     (injected faults, retries, quarantines, sheds, degrade/recover
     transitions) in tick order, with per-event counts.
@@ -117,18 +116,18 @@ def slot_utilization(trace: Trace) -> Optional[float]:
 
 
 def profile_coverage(trace: Trace) -> Optional[dict]:
-    """Fraction of the latest ``chain`` span attributed to named child
-    steps — how much of a profiled run the profiler can explain."""
-    chains = [s for s in trace.spans if s["cat"] == "chain"]
-    if not chains:
+    """Fraction of the latest ``engine.call`` span covered by its child
+    spans — how much of a profiled engine call its phases explain."""
+    calls = [s for s in trace.spans if s["name"] == "engine.call"]
+    if not calls:
         return None
-    kids = _span_children(trace.spans)
-    last = chains[-1]
-    steps = kids.get(last.get("id"), [])
-    child_t = sum(c["dur"] for c in steps)
+    last = calls[-1]
+    kids = _span_children(trace.spans).get(last.get("id"), [])
+    child_t = sum(c["dur"] for c in kids)
     cov = child_t / last["dur"] if last["dur"] > 0 else 0.0
-    return {"chain": last["name"], "span_us": round(last["dur"], 1),
-            "steps": len(steps), "attributed_us": round(child_t, 1),
+    return {"span": last["name"], "span_us": round(last["dur"], 1),
+            "children": sorted({c["name"] for c in kids}),
+            "attributed_us": round(child_t, 1),
             "coverage": round(min(cov, 1.0), 4),
             "signature": last["args"].get("signature")}
 
@@ -192,8 +191,8 @@ def render_text(out: dict) -> str:
             f"{b}={v:.2%}" for b, v in out["backend_share"].items()))
     if out.get("profile"):
         pr = out["profile"]
-        lines.append(f"profile: {pr['chain']} coverage {pr['coverage']:.2%}"
-                     f" over {pr['steps']} steps")
+        lines.append(f"profile: {pr['span']} coverage {pr['coverage']:.2%}"
+                     f" by {', '.join(pr['children'])}")
     if out.get("faults"):
         lines.append("faults: " + json.dumps(out["faults"]["counts"]))
     lines.append(f"top spans (self time, top {len(out['top_spans'])}):")

@@ -4,9 +4,9 @@ profiled compiled engine.
 Pins the layer's three contracts: the export schema round-trips through
 both formats and the report CLI; a disabled tracer costs nothing (no
 per-call allocation beyond a flag check — tracemalloc-verified); and
-``compile_chain(profile=True)`` attributes >= 95% of a profiled run's
-wall time to named fusion-group steps with backend labels while leaving
-the computed outputs bit-identical to the unprofiled engine."""
+``compile_chain(profile=True)`` records the engine's call phases and
+program builds while running the same fused program, outputs
+bit-identical to the unprofiled engine."""
 import json
 import time
 import tracemalloc
@@ -391,39 +391,52 @@ def _mn_case():
 
 
 @pytest.mark.slow
-def test_profile_mode_coverage_and_attribution():
-    import jax
-
+def test_profile_mode_coverage_and_attribution(tmp_path):
     from repro.exec import compile_chain
 
     chain, inputs, params = _mn_case()
     plain = compile_chain(chain)
     eng = compile_chain(chain, profile=True)
     assert eng.tracer is not None and eng.tracer.enabled
-
-    first = eng(inputs, params)            # cold: every step compiles
     spans = [e for e in eng.tracer.events if e["type"] == "span"]
-    assert {s["cat"] for s in spans if s["name"].startswith("chain:")} \
-        == {"chain"}
-    step_spans = [s for s in spans if s["cat"] in ("compile", "execute")]
-    assert {s["cat"] for s in step_spans} == {"compile"}
+    assert [s["name"] for s in spans] == ["compile.partition",
+                                          "compile.plan", "compile.lint"]
+    assert {s["cat"] for s in spans} == {"compile"}
 
-    got = eng(inputs, params)              # warm: steady-state execution
-    for o in got:
-        np.testing.assert_allclose(
-            np.asarray(got[o], np.float32),
-            np.asarray(jax.block_until_ready(plain(inputs, params))[o],
-                       np.float32), rtol=1e-4, atol=1e-5)
+    first = eng(inputs, params)            # cold: the program is built
+    spans = [e for e in eng.tracer.events if e["type"] == "span"][3:]
+    by_name = {s["name"]: s for s in spans}
+    assert [s["name"] for s in spans] == ["engine.call", "engine.args",
+                                          "engine.launch", "engine.compile"]
+    call = by_name["engine.call"]
+    assert call["parent"] is None and call["cat"] == "engine"
+    assert call["args"]["signature"] == eng.signature
+    assert by_name["engine.args"]["parent"] == call["id"]
+    assert by_name["engine.launch"]["parent"] == call["id"]
+    built = by_name["engine.compile"]
+    assert built["parent"] == by_name["engine.launch"]["id"]
+    assert built["args"]["programs"] == 1
+    assert built["args"]["trace_s"] > 0 and built["args"]["compile_s"] > 0
 
+    got = eng(inputs, params)              # warm: nothing is built
     spans = [e for e in eng.tracer.events if e["type"] == "span"]
-    chains = [s for s in spans if s["cat"] == "chain"]
-    last = chains[-1]
-    steps = [s for s in spans if s["parent"] == last["id"]]
-    assert steps and all(s["cat"] == "execute" for s in steps)
-    assert all(s["args"].get("backend") for s in steps)
-    assert all(s["args"]["signature"] == eng._plan.signature for s in steps)
-    coverage = sum(s["dur"] for s in steps) / last["dur"]
-    assert coverage >= 0.95, f"profile coverage {coverage:.3f} < 0.95"
+    assert [s["name"] for s in spans[-3:]] == ["engine.call", "engine.args",
+                                               "engine.launch"]
+    assert sum(s["name"] == "engine.compile" for s in spans) == 1
+    ref = plain(inputs, params)
+    for o in ref:                          # the same fused program
+        np.testing.assert_array_equal(np.asarray(first[o]),
+                                      np.asarray(ref[o]))
+        np.testing.assert_array_equal(np.asarray(got[o]),
+                                      np.asarray(ref[o]))
+
+    path = str(tmp_path / "engine.json")
+    eng.tracer.write(path)
+    prof = summarize(load_trace(path))["profile"]
+    assert prof["span"] == "engine.call"
+    assert prof["children"] == ["engine.args", "engine.launch"]
+    assert prof["coverage"] >= 0.95, prof
+    assert eng.metrics.value("engine_timed_calls") == 2
 
 
 def test_profile_disabled_is_default_and_matches():
@@ -435,7 +448,9 @@ def test_profile_disabled_is_default_and_matches():
     off = compile_chain(chain, profile=True, tracer=Tracer(enabled=False))
     got, ref = off(inputs, params), eng(inputs, params)
     for o in ref:
-        np.testing.assert_allclose(np.asarray(got[o], np.float32),
-                                   np.asarray(ref[o], np.float32),
-                                   rtol=1e-4, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(got[o]),
+                                      np.asarray(ref[o]))
     assert not off.tracer.events
+    # no profiler session and no tracer: no call is timed
+    assert "engine_timed_calls" not in off.metrics.families()
+    assert "engine_timed_calls" not in eng.metrics.families()
