@@ -49,6 +49,7 @@ from ..obs import compiles
 from ..obs.metrics import Metrics
 from .batch import BucketedCache, batch_bucket, pad_leading, unpad_leading
 from .dispatch import Plan, plan_chain
+from .lowering import window_fold
 from .partition import partition_chain
 
 
@@ -108,9 +109,17 @@ class CompiledChain:
         self._batched = BucketedCache(self._build_batched)
         # observability (repro.obs): the spans of __call__ go into
         # ``tracer`` while it is enabled; ``metrics`` holds each program's
-        # build counters and the phase seconds of timed calls
+        # build counters and the phase seconds of timed calls, and, counted
+        # once here, the reduce steps by how their window dims fold
+        # (lowering.window_fold)
         self.tracer = tracer
         self.metrics = Metrics()
+        folds = [window_fold(chain.nodes[s.name]) for s in self.steps
+                 if s.backend == "reduce"]
+        self.metrics.counter("engine_reduce_window_steps").inc(
+            folds.count("reduce_window"))
+        self.metrics.counter("engine_slice_window_steps").inc(
+            folds.count("slice"))
         self._builds = compiles.install()
 
     # -- parameter init (the oracle's own recipe, shared) ---------------
@@ -425,6 +434,10 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
     ``engine_programs_compiled``, ``engine_compile_cache_hits`` and the
     seconds ``engine_trace_s`` (tracing and lowering) and
     ``engine_compile_s`` (XLA/Mosaic compile or persistent-cache load).
+    ``engine_reduce_window_steps`` and ``engine_slice_window_steps``
+    count, once at construction, the ``reduce`` steps whose window dims
+    fold in one ``lax.reduce_window`` or as shifted slices
+    (``lowering.window_fold``).
     ``compile_chain``'s own phases are annotated ``compile.partition``,
     ``compile.plan``, ``compile.tune`` and ``compile.lint``.
 

@@ -11,7 +11,12 @@ loop parameters, paper §3.1):
                    with no window overlap (FC's C dim, softmax's axis,
                    batch-norm's batch axis).
   * ``window``   — true sliding windows (``Nopc > 1`` and ``Nks > 1``) with
-                   stride/padding: conv/pool spatial dims, LRN's C dim.
+                   stride/padding: conv/pool spatial dims, LRN's C dim. A
+                   reduce folds its window dims in the tensor's own dim
+                   order, in one ``lax.reduce_window`` or, for LRN's one
+                   stride-1 dim, as shifted slices (``window_fold``); no
+                   transpose or gather: on a TPU a gathered 3- or 5-tap
+                   last axis fills few of a tile's 128 lanes.
   * ``general``  — anything else (strided decimation etc.): falls back to
                    the oracle interpreter semantics.
 
@@ -52,6 +57,7 @@ order but stays within the engine's differential-test tolerance.
 """
 from __future__ import annotations
 
+import functools
 import string
 from typing import Callable, Optional, Sequence, Tuple
 
@@ -133,20 +139,75 @@ def _reducer(name: str):
     return {"add": jnp.sum, "max": jnp.max, "min": jnp.min}[name]
 
 
+_WINDOW_REDUCER = {"add": jax.lax.add, "max": jax.lax.max,
+                   "min": jax.lax.min}
+_SLICE_MAX_TAPS = 8
+
+
+def window_fold(node: GConv) -> Optional[str]:
+    """How :func:`lower_reduce` folds the node's window dims: ``"slice"``
+    for one window dim of stride 1 and at most ``_SLICE_MAX_TAPS`` taps
+    (LRN's window over C), ``"reduce_window"`` for any other window
+    geometry (pools), None without a window dim.
+
+    Timed alone on a TPU v5e at GoogLeNet's b32 shapes, LRN's window folds
+    about three times faster as shifted slices than as a
+    ``lax.reduce_window``, and a 3x3 stride-2 max pool about eight times
+    slower as strided slices. The tap bound keeps the unrolled fold
+    small."""
+    win = [d for d, c in zip(node.dims, dim_classes(node)) if c == WINDOW]
+    if not win:
+        return None
+    if (len(win) == 1 and win[0].stride == 1
+            and win[0].nks <= _SLICE_MAX_TAPS):
+        return "slice"
+    return "reduce_window"
+
+
 def lower_reduce(node: GConv, classes: Sequence[str]) -> Callable:
+    """Window dims fold as :func:`window_fold` says, over the tensor in its
+    own dim order, then contract dims fold in one axis reduction.
+
+    Padded taps read the reduce's identity, as the oracle pads; a negative
+    ``padr`` first crops the trailing elements no window reads. One
+    ``lax.reduce_window`` folds every window dim at once; the ``slice``
+    fold pads its one window dim and combines the ``Nks`` shifted slices
+    of it. No :func:`_window_gather` per dim: on a TPU that puts a 3- or
+    5-tap last axis on the 128 lanes of a tile and copies the whole
+    tensor on each side of it (the transposes)."""
     dims = node.dims
     red = _reducer(node.reduce)
-    pad_val = ops.pad_value(node.reduce)
-    window_ix = [i for i, c in enumerate(classes) if c == WINDOW]
+    combine = _WINDOW_REDUCER[node.reduce]
+    init = ops.pad_value(node.reduce)
+    fold = window_fold(node)
+    is_win = [c == WINDOW for c in classes]
     contract_ix = [i for i, c in enumerate(classes) if c == CONTRACT]
+    window = tuple(d.nks if w else 1 for d, w in zip(dims, is_win))
+    strides = tuple(d.stride if w else 1 for d, w in zip(dims, is_win))
+    padding = tuple((d.pad, max(d.padr, 0)) if w else (0, 0)
+                    for d, w in zip(dims, is_win))
+    crop = tuple(d.in_size + min(d.padr, 0) if w else d.in_size
+                 for d, w in zip(dims, is_win))
+
+    def fold_slices(x):
+        i = is_win.index(True)
+        d = dims[i]
+        x = jnp.pad(x, padding, constant_values=init)
+        taps = [jax.lax.slice_in_dim(x, t, t + d.nopc, axis=i)
+                for t in range(d.nks)]
+        return functools.reduce(combine, taps)
 
     def fn(x, k, lookup):
         x = x.astype(_compute_dtype(x))
         x = ops.apply_unary_seq(node.pre, x, lookup)
-        for i in window_ix:             # window + immediate fold, per dim
-            w = _window_gather(x, i, dims[i], pad_val)
-            w = red(w, axis=-1)         # (…, Nopc)
-            x = jnp.moveaxis(w, -1, i)
+        if fold is not None:
+            if crop != x.shape:         # trailing elements never read
+                x = jax.lax.slice(x, (0,) * x.ndim, crop)
+            if fold == "slice":
+                x = fold_slices(x)
+            else:
+                x = jax.lax.reduce_window(x, init, combine, window, strides,
+                                          padding)
         if contract_ix:
             shape, axes = [], []
             for i, d in enumerate(dims):

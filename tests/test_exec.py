@@ -16,7 +16,8 @@ from repro.core.fusion import fuse_chain
 from repro.core.gconv import DimSpec, GConv, Op
 from repro.core.interpreter import ChainExecutor, eval_gconv
 from repro.core import layers as L
-from repro.exec import compile_chain, execute_gconv
+from repro.exec import compile_chain, dispatch_gconv, execute_gconv
+from repro.exec import lowering as low
 from repro.models import cnn, lm_chain
 from repro.models.common import ModelConfig
 
@@ -271,6 +272,131 @@ def test_compiled_gconv_broadcast_kernel():
     want = np.asarray(eval_gconv(g, x, kk))
     got = np.asarray(execute_gconv(g, x, kk))
     np.testing.assert_allclose(got, want, **TOL)
+
+
+# ---------------------------------------------------------------------------
+# window reduces (pools, LRN's channel window): reduce_window and slices
+# ---------------------------------------------------------------------------
+def _win(name, nopc, nks, stride=1, pad=0, pad_r=None):
+    return DimSpec(name, nopc=nopc, nks=nks, stride=stride, pad=pad,
+                   pad_r=pad_r)
+
+
+_BC = (DimSpec("B", ng=2), DimSpec("C", ng=3))
+_LRN_POST = (Op("scale", const=1e-4 / 5), Op("add_const", const=2.0),
+             Op("pow", const=-0.75))
+# (reduce, dims, pre, post): padding, ceil mode (padr > pad), crops
+# (padr < 0), strides 1, 2 and > nks, 3-D, LRN's C window, and one
+# stride-1 window dim on both sides of the slice fold's tap bound
+WINDOW_CASES = {
+    "max-pad1-s1": ("max", _BC + (_win("H", 6, 3, 1, 1),
+                                  _win("W", 5, 3, 1, 1)), (), ()),
+    "max-pad1-s2": ("max", _BC + (_win("H", 4, 3, 2, 1),
+                                  _win("W", 4, 3, 2, 1)), (), ()),
+    "max-ceil": ("max", _BC + (_win("H", 4, 3, 2, 0, 1),
+                               _win("W", 3, 3, 2, 0, 1)), (), ()),
+    "max-crop": ("max", _BC + (_win("H", 3, 3, 2, 0, -1),
+                               _win("W", 3, 3, 2, 1, -1)), (), ()),
+    "max-stride-gt-nks": ("max", _BC + (_win("H", 3, 2, 3),
+                                        _win("W", 2, 2, 3, 0, -2)), (), ()),
+    "min-ceil-pad1": ("min", _BC + (_win("H", 4, 3, 2, 1, 2),
+                                    _win("W", 4, 3, 2, 1)), (), ()),
+    "add-avg-pad1": ("add", _BC + (_win("H", 5, 3, 1, 1),
+                                   _win("W", 3, 3, 2, 1, 0)),
+                     (), (Op("scale", const=1.0 / 9),)),
+    "add-ceil-crop": ("add", _BC + (_win("H", 4, 3, 2, 0, 1),
+                                    _win("W", 3, 3, 2, 0, -1)), (), ()),
+    "max-3d": ("max", _BC + (_win("T", 2, 2, 2, 0, -1), _win("H", 3, 3, 2, 1),
+                             _win("W", 3, 3, 2, 1, 2)), (), ()),
+    "max-window-and-contract": ("max", (DimSpec("B", ng=2),
+                                        DimSpec("C", ng=2, nks=3),
+                                        _win("H", 4, 3, 2, 1)), (), ()),
+    "lrn": ("add", (DimSpec("B", ng=2), _win("C", 7, 5, 1, 2),
+                    DimSpec("H", ng=3), DimSpec("W", ng=4)),
+            (Op("square"),), _LRN_POST),
+    "max-1d-s1-crop": ("max", _BC + (_win("T", 5, 3, 1, 1, -1),), (), ()),
+    "min-1d-s1-ceil": ("min", _BC + (_win("T", 4, 3, 1, 0, 2),), (), ()),
+    "add-1d-9-taps": ("add", _BC + (_win("T", 6, 9, 1, 4),), (), ()),
+}
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["exact", "vmapped"])
+@pytest.mark.parametrize("case", list(WINDOW_CASES))
+def test_window_reduce_matches_oracle(case, batched):
+    reduce, dims, pre, post = WINDOW_CASES[case]
+    g = GConv(name="g", dims=dims, input="x", main="none", reduce=reduce,
+              pre=pre, post=post)
+    tag, _ = dispatch_gconv(g, None)
+    assert tag == "reduce"
+    xs = jax.random.normal(jax.random.PRNGKey(len(case)), (3,) + g.in_shape)
+    if batched:
+        got = jax.jit(jax.vmap(lambda x: execute_gconv(g, x)))(xs)
+    else:
+        got = jnp.stack([jax.jit(lambda x: execute_gconv(g, x))(x)
+                         for x in xs])
+    want = np.stack([np.asarray(eval_gconv(g, x, None)) for x in xs])
+    if reduce == "add":                 # the taps sum in another order
+        np.testing.assert_allclose(np.asarray(got), want, **TOL)
+    else:                               # max and min are exact
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def _primitives(jaxpr):
+    """Every primitive name in ``jaxpr``, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)     # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("reduced", [True, False], ids=["reduced", "full"])
+def test_window_reduces_fold_without_gather(reduced):
+    """Each window-reduce step of a GoogLeNet chain (batch 2) folds in one
+    reduce_window (pools) or as shifted slices (LRN), with no transpose or
+    gather around it, and the engine counts each kind. Traced on shapes
+    alone: nothing runs."""
+    eng = compile_chain(cnn.build("GLN", reduced=reduced, batch=2))
+    chain = eng.chain
+
+    def spec(infos):
+        return {n: jax.ShapeDtypeStruct(i.shape, jnp.dtype(i.dtype))
+                for n, i in infos.items()}
+
+    inputs, params = spec(chain.inputs), spec(chain.params)
+    whole = jax.make_jaxpr(lambda i, p: eng._execute(i, p, False))(
+        inputs, params).jaxpr
+    n_windows = sum(p.startswith("reduce_window") for p in _primitives(whole))
+    steps = {s.name: s for s in eng.steps if s.backend == "reduce"
+             and low.window_fold(chain.nodes[s.name]) is not None}
+    folds = {n: low.window_fold(chain.nodes[n]) for n in steps}
+    n_rw = sum(f == "reduce_window" for f in folds.values())
+    assert n_rw and n_windows == n_rw
+    assert eng.metrics.value("engine_reduce_window_steps") == n_rw
+    assert eng.metrics.value("engine_slice_window_steps") == len(folds) - n_rw
+    env = dict(inputs, **params)
+    env.update((n, jax.ShapeDtypeStruct(chain.shape_of(n), jnp.float32))
+               for n in chain.nodes)
+    for name, fold in folds.items():
+        prims = list(_primitives(jax.make_jaxpr(steps[name].run)(env).jaxpr))
+        n = sum(p.startswith("reduce_window") for p in prims)
+        assert n == (fold == "reduce_window"), name
+        assert not {"gather", "transpose"} & set(prims), name
+        if fold == "reduce_window":
+            assert "pad" not in prims, name
+
+
+@pytest.mark.parametrize("name,rw,sl", [("GLN", 13, 2), ("AN", 3, 2)])
+def test_reduce_window_step_count(name, rw, sl):
+    """Full-size plans (planning traces nothing). GoogLeNet: 4 stride-2
+    max pools and 9 inception pools fold in reduce_window, its 2 LRN
+    windows as slices; AlexNet: 3 pools, 2 LRN windows. Global average
+    pools are contractions, not windows."""
+    eng = compile_chain(cnn.build(name))
+    assert eng.metrics.value("engine_reduce_window_steps") == rw
+    assert eng.metrics.value("engine_slice_window_steps") == sl
 
 
 # ---------------------------------------------------------------------------
