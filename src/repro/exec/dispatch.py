@@ -107,8 +107,15 @@ def dispatch_gconv(node: GConv, k_shape: Optional[Tuple[int, ...]],
                 return "matmul:jnp", low.lower_grouped_matmul(node, plan)
         cplan = low.match_conv(node, classes, k_shape)
         if cplan is not None:
-            if backend == "pallas" or (backend == "auto"
-                                       and not use_interpret()):
+            pallas = backend == "pallas" or (backend == "auto"
+                                             and not use_interpret())
+            if pallas and low.is_depthwise(node, cplan):
+                # the Pallas kernel on the VPU; ``:pallas`` alone names
+                # the MXU kernels (matmul:pallas, conv:pallas)
+                fn = low.lower_depthwise_pallas(node, cplan)
+                if fn is not None:
+                    return "dwconv:pallas-vpu", fn
+            elif pallas:
                 fn = low.lower_conv_pallas(node, cplan)
                 if fn is not None:
                     return "conv:pallas", fn

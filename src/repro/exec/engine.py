@@ -45,11 +45,12 @@ from jax.profiler import TraceAnnotation
 
 from ..core.chain import Chain
 from ..core.fusion import ExecGroup, FusionReport
+from ..core.gconv import GConv
 from ..obs import compiles
 from ..obs.metrics import Metrics
 from .batch import BucketedCache, batch_bucket, pad_leading, unpad_leading
 from .dispatch import Plan, plan_chain
-from .lowering import window_fold
+from .lowering import dim_classes, is_depthwise, match_conv, window_fold
 from .partition import partition_chain
 
 
@@ -111,7 +112,7 @@ class CompiledChain:
         # ``tracer`` while it is enabled; ``metrics`` holds each program's
         # build counters and the phase seconds of timed calls, and, counted
         # once here, the reduce steps by how their window dims fold
-        # (lowering.window_fold)
+        # (lowering.window_fold) and the depthwise convs by backend
         self.tracer = tracer
         self.metrics = Metrics()
         folds = [window_fold(chain.nodes[s.name]) for s in self.steps
@@ -120,6 +121,11 @@ class CompiledChain:
             folds.count("reduce_window"))
         self.metrics.counter("engine_slice_window_steps").inc(
             folds.count("slice"))
+        dw = [s.backend for s in self.steps if _is_depthwise(chain, s.name)]
+        for backend in sorted(set(dw) | {"dwconv:pallas-vpu",
+                                         "conv:lax"}):
+            self.metrics.counter("engine_depthwise_steps",
+                                 backend=backend).inc(dw.count(backend))
         self._builds = compiles.install()
 
     # -- parameter init (the oracle's own recipe, shared) ---------------
@@ -405,6 +411,16 @@ def hlo_op_steps(text: str, steps) -> Dict[str, str]:
     return out
 
 
+def _is_depthwise(chain: Chain, name: str) -> bool:
+    """Is the step ``name`` a depthwise conv (``lowering.is_depthwise``)?"""
+    node = chain.nodes.get(name)
+    if not isinstance(node, GConv) or node.kernel is None:
+        return False
+    plan = match_conv(node, dim_classes(node),
+                      tuple(chain.shape_of(node.kernel)))
+    return plan is not None and is_depthwise(node, plan)
+
+
 @contextmanager
 def _phase(name: str, tracer):
     """One set-up phase of ``compile_chain``: a profiler annotation, and a
@@ -437,7 +453,9 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
     ``engine_reduce_window_steps`` and ``engine_slice_window_steps``
     count, once at construction, the ``reduce`` steps whose window dims
     fold in one ``lax.reduce_window`` or as shifted slices
-    (``lowering.window_fold``).
+    (``lowering.window_fold``); ``engine_depthwise_steps`` counts the
+    depthwise conv steps (``lowering.is_depthwise``) by ``backend``
+    (``dwconv:pallas-vpu`` where the kernel takes them, else ``conv:lax``).
     ``compile_chain``'s own phases are annotated ``compile.partition``,
     ``compile.plan``, ``compile.tune`` and ``compile.lint``.
 

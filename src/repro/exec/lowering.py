@@ -250,11 +250,26 @@ def match_conv(node: GConv, classes: Sequence[str],
     return channel[0], windows, batch
 
 
+def is_depthwise(node: GConv, plan) -> bool:
+    """A conv (``match_conv``'s plan) whose channel dim is ``Ng = C,
+    Nks = 1, Nop = 1``: each channel its own window, nothing contracted
+    over channels (match_conv's depthwise branch)."""
+    d = node.dims[plan[0]]
+    return d.nks == 1 and d.nop == 1
+
+
 def lower_conv(node: GConv, plan) -> Callable:
+    """``lax.conv_general_dilated`` at XLA's default precision, except a
+    depthwise conv (:func:`is_depthwise`), which runs at
+    ``Precision.HIGHEST``: float32 products and sums, the arithmetic of
+    :func:`lower_depthwise_pallas`, so its plan means the same numbers on
+    every backend."""
     ch, windows, batch = plan
     dims = node.dims
     dch = dims[ch]
     groups, ocg, icg = dch.ng, dch.nop, dch.nks
+    precision = (jax.lax.Precision.HIGHEST if is_depthwise(node, plan)
+                 else None)
     spatial = "".join("xyzuv"[i] for i in range(len(windows)))
     dn = ("NC" + spatial, "OI" + spatial, "NC" + spatial)
     strides = tuple(dims[i].stride for i in windows)
@@ -282,7 +297,7 @@ def lower_conv(node: GConv, plan) -> Callable:
                         + tuple(dims[i].nks for i in windows))
         y = jax.lax.conv_general_dilated(
             xb, kb, strides, padding, dimension_numbers=dn,
-            feature_group_count=groups)
+            feature_group_count=groups, precision=precision)
         # (N, G*Nop, *Nopc) -> original dim order -> out_shape
         y = y.reshape(tuple(b_sizes) + (groups * ocg,)
                       + tuple(dims[i].nopc for i in windows))
@@ -357,6 +372,65 @@ def lower_conv_pallas(node: GConv, plan,
                           block_o=block_o)
         y = jnp.transpose(y, (0, 3, 1, 2))
         y = y.reshape(tuple(b_sizes) + (dch.nop, dh.nopc, dw.nopc))
+        y = jnp.transpose(y, np.argsort(perm)).reshape(node.out_shape)
+        return _finish(node, y, lookup)
+
+    return fn
+
+
+def depthwise_refusal(node: GConv, plan) -> Optional[str]:
+    """Why :func:`lower_depthwise_pallas` will not take this depthwise
+    conv, or None when it does. Checked from the ``DimSpec``s alone, so the
+    plan is the same in interpret mode and on the chip."""
+    from ..kernels.gconv_depthwise import mosaic_refusal
+
+    ch, windows, batch = plan
+    dims = node.dims
+    if not is_depthwise(node, plan):
+        return "not depthwise"
+    if len(windows) != 2:
+        return f"{len(windows)} window dims, the kernel's plane has 2"
+    dh, dw = dims[windows[0]], dims[windows[1]]
+    if (dh.nks, dh.stride, dh.pad) != (dw.nks, dw.stride, dw.pad):
+        return "window, stride or padding differ between H and W"
+    for d in (dh, dw):
+        # symmetric padding must give the chain's output size; a right
+        # pad below the left one only drops taps no output reads
+        if (d.nips + 2 * d.pad - d.nks) // d.stride + 1 != d.nopc \
+                or d.padr > d.pad:
+            return (f"padding ({d.pad}, {d.padr}) is not the kernel's "
+                    f"symmetric {d.pad}")
+    return mosaic_refusal(dh.nips, dw.nips, dims[ch].ng, dh.nks,
+                          stride=dh.stride, pad=dh.pad)
+
+
+def lower_depthwise_pallas(node: GConv, plan) -> Optional[Callable]:
+    """Channels-last Pallas depthwise kernel (``kernels.gconv_depthwise``,
+    float32 on the VPU); None where :func:`depthwise_refusal` names a
+    reason, and the caller dispatches to :func:`lower_conv` at
+    ``Precision.HIGHEST``."""
+    if depthwise_refusal(node, plan) is not None:
+        return None
+    from ..kernels.gconv_depthwise import gconv_depthwise
+
+    ch, windows, batch = plan
+    dims = node.dims
+    dch, dh, dw = dims[ch], dims[windows[0]], dims[windows[1]]
+    perm = batch + [ch] + windows
+    b_sizes = [dims[i].in_size for i in batch]
+    nb = int(np.prod(b_sizes)) if b_sizes else 1
+
+    def fn(x, k, lookup):
+        ct = _compute_dtype(x)
+        x = x.astype(ct)
+        x = ops.apply_unary_seq(node.pre, x, lookup)
+        xb = jnp.transpose(x, perm).reshape(nb, dch.ng, dh.nips, dw.nips)
+        xb = jnp.transpose(xb, (0, 2, 3, 1))                 # NHWC
+        kb = jnp.transpose(k.astype(ct), [ch] + windows + batch)
+        kb = jnp.transpose(kb.reshape(dch.ng, dh.nks, dw.nks), (1, 2, 0))
+        y = gconv_depthwise(xb, kb, stride=dh.stride, pad=dh.pad)
+        y = jnp.transpose(y, (0, 3, 1, 2))
+        y = y.reshape(tuple(b_sizes) + (dch.ng, dh.nopc, dw.nopc))
         y = jnp.transpose(y, np.argsort(perm)).reshape(node.out_shape)
         return _finish(node, y, lookup)
 

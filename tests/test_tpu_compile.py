@@ -20,6 +20,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.exec import compile_chain
 from repro.kernels.chain_norm import chain_norm
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.gconv_depthwise import gconv_depthwise
+from repro.kernels.gconv_depthwise import mosaic_refusal as dw_refusal
 from repro.kernels.gconv_matmul import gconv_matmul
 from repro.kernels.gconv_spatial import gconv_spatial, mosaic_refusal
 from repro.models import cnn
@@ -88,6 +90,23 @@ def test_gconv_spatial_compiles(one_chip, geom):
     assert mosaic_refusal(H, W, C, K, K, O, stride=1, pad=pad) is None
     _compile(functools.partial(gconv_spatial, pad=pad, interpret=False),
              one_chip, (B, H, W, C), (K, K, C, O))
+
+
+# (B, H, W, C, stride) of MobileNet's depthwise convs at b32: the largest
+# plane (32 channels on 128 lanes), the stride-2 phase split at 112, and
+# the 14x14 and 7x7 planes that most of them have
+DEPTHWISE = {"MN.dw0": (32, 112, 112, 32, 1), "MN.dw1": (32, 112, 112, 64, 2),
+             "MN.dw6": (32, 14, 14, 512, 1), "MN.dw11": (32, 14, 14, 512, 2),
+             "MN.dw12": (32, 7, 7, 1024, 1)}
+
+
+@pytest.mark.parametrize("geom", list(DEPTHWISE.values()), ids=list(DEPTHWISE))
+def test_gconv_depthwise_compiles(one_chip, geom):
+    B, H, W, C, stride = geom
+    assert dw_refusal(H, W, C, 3, stride=stride, pad=1) is None
+    _compile(functools.partial(gconv_depthwise, stride=stride, pad=1,
+                               interpret=False),
+             one_chip, (B, H, W, C), (3, 3, C))
 
 
 def test_chain_norm_compiles(one_chip):
