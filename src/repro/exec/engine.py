@@ -127,6 +127,11 @@ class CompiledChain:
             self.metrics.counter("engine_depthwise_steps",
                                  backend=backend).inc(dw.count(backend))
         self._builds = compiles.install()
+        # what a call takes from the caller's dicts, and how many of those
+        # values were not yet jax.Arrays (counted only when some were)
+        self._input_names = tuple(chain.inputs)
+        self._param_names = tuple(chain.params)
+        self._converted = self.metrics.counter("engine_args_converted")
 
     # -- parameter init (the oracle's own recipe, shared) ---------------
     def init_params(self, key, scale: float = 0.1) -> Dict[str, jnp.ndarray]:
@@ -211,18 +216,13 @@ class CompiledChain:
 
     def _args(self, inputs, params):
         """The chain's inputs and parameters as arrays, and the leading
-        batch size (None for exact shapes)."""
-        params = params or {}
-        ins = {}
-        for name in self.chain.inputs:
-            if name not in inputs:
-                raise ValueError(f"missing chain input {name!r}")
-            ins[name] = jnp.asarray(inputs[name])
-        ps = {}
-        for name in self.chain.params:
-            if name not in params:
-                raise ValueError(f"missing chain param {name!r}")
-            ps[name] = jnp.asarray(params[name])
+        batch size (None for exact shapes). A value that is already a
+        ``jax.Array`` (a tracer too) is passed on as it is, which is what
+        ``jnp.asarray`` would return; any other goes through it."""
+        ins, n_in = _take(inputs, self._input_names, "input")
+        ps, n_ps = _take(params or {}, self._param_names, "param")
+        if n_in or n_ps:
+            self._converted.inc(n_in + n_ps)
         return ins, ps, self._batch_size(ins)
 
     def _launch(self, ins, ps, n, keep_all):
@@ -360,6 +360,21 @@ class CompiledChain:
         return "\n".join(lines)
 
 
+def _take(given, names, kind):
+    """``{name: array}`` of ``names`` from ``given`` and how many of them
+    ``jnp.asarray`` had to convert; a missing name raises ValueError."""
+    out, converted = {}, 0
+    for name in names:
+        if name not in given:
+            raise ValueError(f"missing chain {kind} {name!r}")
+        value = given[name]
+        if not isinstance(value, jax.Array):
+            value = jnp.asarray(value)
+            converted += 1
+        out[name] = value
+    return out, converted
+
+
 _HLO_INST = re.compile(r"^\s*(?:ROOT )?%([^\s=]+) = ")
 _HLO_REF = re.compile(r"%([^\s,(){}=]+)")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -441,12 +456,14 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
     Tracing (``repro.obs``): every call runs the one fused program, with
     one ``jax.named_scope`` per fusion-group step (``engine.op_steps()``
     maps the compiled program's HLO instructions to steps), inside the
-    host spans ``engine.call`` > ``engine.args`` (input checks and
-    ``jnp.asarray``) / ``engine.launch`` (the jitted program, until it
-    returns its unfinished outputs), written as ``jax.profiler``
-    annotations while a profiler session records, so that they land in its
-    trace beside the device's operations. A call that builds
-    a program counts it in ``engine.metrics`` under its program key:
+    host spans ``engine.call`` > ``engine.args`` (the chain's inputs and
+    params taken from the caller's dicts, ``jnp.asarray`` on those that are
+    not yet ``jax.Array``s, the input shapes checked) / ``engine.launch``
+    (the jitted program, until it returns its unfinished outputs), written
+    as ``jax.profiler`` annotations while a profiler session records, so
+    that they land in its trace beside the device's operations. A call
+    that builds a program counts it in ``engine.metrics`` under its
+    program key:
     ``engine_programs_compiled``, ``engine_compile_cache_hits`` and the
     seconds ``engine_trace_s`` (tracing and lowering) and
     ``engine_compile_s`` (XLA/Mosaic compile or persistent-cache load).
@@ -456,6 +473,8 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
     (``lowering.window_fold``); ``engine_depthwise_steps`` counts the
     depthwise conv steps (``lowering.is_depthwise``) by ``backend``
     (``dwconv:pallas-vpu`` where the kernel takes them, else ``conv:lax``).
+    ``engine_args_converted`` counts the values a call had to convert
+    (NumPy arrays, Python scalars, lists); device arrays count nothing.
     ``compile_chain``'s own phases are annotated ``compile.partition``,
     ``compile.plan``, ``compile.tune`` and ``compile.lint``.
 

@@ -3,8 +3,10 @@
 Dependency-free (stdlib only at import time) so every layer can emit
 through it: the compiled engine's spans and build counters (its
 ``profile=True`` mode records the spans ``engine.call`` > ``engine.args``
-/ ``engine.launch`` > ``engine.compile`` of each call of the one fused
-program, and ``compile.*`` of ``compile_chain``, into a tracer), the
+(the call's inputs and params taken, ``jnp.asarray`` only on values that
+are not yet ``jax.Array``s) / ``engine.launch`` > ``engine.compile`` of
+each call of the one fused program, and ``compile.*`` of
+``compile_chain``, into a tracer), the
 serving driver's ``--trace`` request-lifecycle trace, the simulator's
 stats and the benchmark harness's provenance-stamped artifacts.
 
