@@ -27,11 +27,10 @@ import time
 # "obs_micro" (the FAST-tier smokes) likewise only run via --only.
 ALL = ("table1", "fig12", "fig13", "fig14", "fig15", "fusion", "fig18",
        "fig20", "kernels", "roofline", "exec", "exec_sharded", "dse",
-       "serve", "syssim", "lint", "tune")
+       "serve", "syssim", "lint")
 
 MICRO = ("exec_micro", "dse_micro", "serve_micro", "exec_sharded_micro",
-         "obs_micro", "chaos_micro", "syssim_micro", "lint_micro",
-         "tune_micro")
+         "obs_micro", "chaos_micro", "syssim_micro", "lint_micro")
 
 
 def _run(name, fn):
@@ -162,8 +161,7 @@ def main():
         want = list(ALL)
 
     from benchmarks import (chaos_bench, dse_bench, exec_bench, lint_bench,
-                            obs_bench, serve_bench, syssim_bench,
-                            tune_bench)
+                            obs_bench, serve_bench, syssim_bench)
     from benchmarks import paper_tables as pt
     from repro.obs import Metrics, provenance
 
@@ -187,8 +185,6 @@ def main():
         "syssim_micro": syssim_bench.syssim_micro,
         "lint": lint_bench.lint_scan,
         "lint_micro": lint_bench.lint_micro,
-        "tune": tune_bench.tune_speedup,
-        "tune_micro": tune_bench.tune_micro,
     }
     # harness wall-times go through the unified metrics registry so the
     # committed artifact carries the same schema every other subsystem emits
@@ -279,12 +275,6 @@ def main():
             "error findings, and the --mutants run must exit nonzero "
             "with every seeded mutant caught by its intended rule and "
             "no false positives on the clean bases")
-    if "tune_micro" in results and not results["tune_micro"][1].get("ok"):
-        raise SystemExit(
-            "tune_micro: the autotuned plan regressed past noise vs the "
-            "heuristic plan on the smoke network, the warm-cache tuned "
-            "compile exceeded its 5% overhead budget over a plain "
-            "compile, or tuned outputs diverged from the heuristic plan")
 
 
 if __name__ == "__main__":

@@ -77,13 +77,10 @@ if [ "${FAST:-0}" = "1" ]; then
   # ... and the static-analysis smoke: the repro.lint CLI must exit 0
   # with zero error findings on the clean reduced corpus, and exit
   # nonzero on the seeded mutation corpus with every mutant caught by
-  # its intended rule (lint_micro), and the autotuner smoke: a tuned
-  # compile against a throwaway DB must not regress past noise vs the
-  # heuristic plan, diverge from it, or exceed the 5% warm-cache
-  # compile-overhead budget (tune_micro)
+  # its intended rule (lint_micro)
   PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
     python -m benchmarks.run \
-    --only exec_micro,dse_micro,serve_micro,exec_sharded_micro,obs_micro,chaos_micro,syssim_micro,lint_micro,tune_micro
+    --only exec_micro,dse_micro,serve_micro,exec_sharded_micro,obs_micro,chaos_micro,syssim_micro,lint_micro
 fi
 
 # pyflakes-class static checks (config in pyproject [tool.ruff]); the

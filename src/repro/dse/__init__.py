@@ -10,10 +10,11 @@ the cycle-level simulator (``repro.sim``) for validation.
 """
 from .evaluate import (EvalRecord, Evaluator, SUITES, area_proxy, geomean,
                        load_suite, pareto_front, suite_names)
-from .search import (STRATEGIES, GeneticSearch, RandomSearch, SearchResult,
-                     SimulatedAnnealing, search_mapping)
+from .search import search_mapping
 from .space import (FIELDS, PRIORITIES, TEMPORAL_PRIORITIES, Point,
                     SpecSpace, baseline_points)
+from .strategies import (STRATEGIES, GeneticSearch, RandomSearch,
+                         SearchResult, SimulatedAnnealing)
 
 
 def __getattr__(name):

@@ -16,9 +16,8 @@ GCONV mappings for the best point's spec, and writes three artifacts to
     sim cross-check, baseline-domination verdicts and the mapping-search
     report;
   * ``trajectory.json`` — best-fitness-vs-evaluations convergence curve in
-    the shared ``repro.search.trajectory/v1`` schema (``metric: "wlc"``,
-    ``[{n, fitness, best_fitness}...]`` in evaluation order), directly
-    comparable with the kernel-tuner trajectories under ``results/tune/``.
+    the ``repro.search.trajectory/v1`` schema (``metric: "wlc"``,
+    ``[{n, fitness, best_fitness}...]`` in evaluation order).
 
 Exit status is nonzero when a promoted point violates the analytic-vs-sim
 agreement contract (``repro.sim.validate``) — the searched designs must stay
@@ -36,10 +35,10 @@ from typing import Dict, List, Optional, Sequence
 from repro.core import accelerators as acc
 
 from .evaluate import SUITES, EvalRecord, Evaluator, load_suite, pareto_front
-from repro.search import TrajectoryRecorder
-
-from .search import STRATEGIES, SearchResult, search_mapping
+from .search import search_mapping
 from .space import SpecSpace, baseline_points
+from .strategies import STRATEGIES, SearchResult
+from .trajectory import TrajectoryRecorder
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dse")
@@ -101,9 +100,9 @@ def run_dse(suite: str = "zoo", budget: int = 200, seed: int = 0,
 
     # ---- search trajectory: best fitness vs evaluations -------------------
     # Evaluator.cache preserves insertion order, so `records` IS the
-    # evaluation order; the shared recorder's running minimum is the
+    # evaluation order; the recorder's running minimum is the
     # convergence curve the strategy benchmarks (and the archgym-style viz
-    # loop) consume — same schema as the kernel-tuner trajectories.
+    # loop) consume.
     recorder = TrajectoryRecorder(metric="wlc")
     recorder.extend([rec.wlc for rec in records])
     best_so_far = recorder.best_fitness

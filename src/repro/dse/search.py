@@ -1,13 +1,6 @@
-"""DSE-facing search surface: shared strategy engines + mapping search.
-
-The spec-space strategy engines (seeded random sampling, simulated
-annealing, elitist genetic search, plus the budget-counting scorer and
-result record) live in the shared :mod:`repro.search` package — the DSE is
-one consumer (accelerator-spec index tuples, analytic WLC objective), the
-kernel autotuner (:mod:`repro.exec.tune`) is another. This module re-exports
-them under their historical names so ``repro.dse.search.STRATEGIES`` et al.
-keep working, and keeps the chain-level *mapping* search, which is
-DSE-specific.
+"""Chain-level mapping search. The spec-space strategy engines (seeded
+random sampling, simulated annealing, elitist genetic search) live in
+:mod:`repro.dse.strategies`.
 
 Mapping search (:func:`search_mapping`): a chain-level hill climb over
 Algorithm-1 *priority variants* — per the paper (§4.4), accelerators differ
@@ -28,27 +21,8 @@ from typing import Dict, Tuple
 from repro.core.costmodel import gconv_chain_cost
 from repro.core.gconv import GConv
 from repro.core.mapping import Mapping, map_gconv
-from repro.search import (
-    BudgetExhausted,
-    GeneticSearch,
-    RandomSearch,
-    Scorer,
-    SearchResult,
-    SimulatedAnnealing,
-    Strategy,
-    STRATEGIES,
-)
 
-from .space import PRIORITIES, TEMPORAL_PRIORITIES, Point  # noqa: F401
-
-# historical private name, still used by tests exercising budget accounting
-_Scorer = Scorer
-
-__all__ = [
-    "BudgetExhausted", "GeneticSearch", "Point", "RandomSearch",
-    "SearchResult", "SimulatedAnnealing", "Strategy", "STRATEGIES",
-    "_Scorer", "search_mapping",
-]
+from .space import PRIORITIES, TEMPORAL_PRIORITIES
 
 
 # ---------------------------------------------------------------------------

@@ -29,6 +29,9 @@ from typing import Dict, List, Tuple
 
 from repro.core.accelerators import AcceleratorSpec, SpatialDim
 
+# A point is an index tuple: hashable (the strategies' memo table) and
+# totally ordered (``min(..., key=lambda ps: (score, point))`` tie-breaks
+# are deterministic under a fixed seed).
 Point = Tuple[int, ...]
 
 # Algorithm-1 parameter priorities offered to the search (spatial axes).

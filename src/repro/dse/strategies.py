@@ -1,13 +1,12 @@
-"""Seeded search strategies over a generic :class:`PointSpace`.
+"""Seeded search strategies over a :class:`~repro.dse.space.SpecSpace`.
 
 Three strategies behind one :class:`Strategy` protocol — seeded random
 sampling, simulated annealing, and a small elitist genetic search. All draw
 exclusively from a ``random.Random(seed)`` stream and iterate deterministic
 data structures, so a fixed seed reproduces the exact evaluation history
-and best point. The engines know nothing about what a point *means*: the
-DSE layer feeds accelerator-spec index tuples scored by the analytic cost
-model, the kernel tuner feeds (backend, block) index tuples scored by
-measured on-device latency.
+and best point. The engines know nothing about what a point *means*: they
+call only the space's ``sample``/``mutate``/``crossover`` and the
+objective (the analytic cost model's whole-life cost).
 """
 from __future__ import annotations
 
@@ -16,7 +15,7 @@ import random
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Protocol, Sequence, Tuple
 
-from .space import Point, PointSpace
+from .space import Point, SpecSpace
 
 
 class BudgetExhausted(Exception):
@@ -69,7 +68,7 @@ class SearchResult:
 class Strategy(Protocol):
     name: str
 
-    def run(self, space: PointSpace, objective: Callable[[Point], float],
+    def run(self, space: SpecSpace, objective: Callable[[Point], float],
             budget: int, seed: int = 0,
             seeds: Sequence[Point] = ()) -> SearchResult:
         """Spend up to ``budget`` unique evaluations minimizing
@@ -107,8 +106,7 @@ class RandomSearch:
 class SimulatedAnnealing:
     """Single-chain Metropolis walk with a geometric temperature schedule.
     Defaults are calibrated to objectives normalized near 1.0 (the WLC
-    scale, where ER == 1.0); measured-latency consumers normalize or pass
-    their own ``t0``/``t1``."""
+    scale, where ER == 1.0)."""
 
     name = "anneal"
 

@@ -1,9 +1,8 @@
-"""Shared best-fitness-vs-evaluations trajectory recorder.
+"""Best-fitness-vs-evaluations trajectory recorder.
 
-Every search consumer (``repro.dse.run``, ``repro.exec.tune``) emits the
-same convergence-curve schema so strategy benchmarks and the viz loop can
-overlay runs regardless of what the fitness *is* (analytic WLC, measured
-microseconds): ``{"schema": "repro.search.trajectory/v1", "metric": ...,
+``repro.dse.run`` writes its convergence curve in this schema so strategy
+benchmarks and the viz loop can overlay runs:
+``{"schema": "repro.search.trajectory/v1", "metric": ...,
 "trajectory": [{"n": 1, "fitness": ..., "best_fitness": ...}, ...]}``.
 """
 from __future__ import annotations

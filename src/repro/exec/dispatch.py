@@ -64,9 +64,9 @@ class Plan:
 # per-node dispatch
 # ---------------------------------------------------------------------------
 def _prefer_pallas_matmul(backend: str, mxu_min: int, plan, node) -> bool:
-    """Static MXU-worthiness heuristic — the no-DB fallback the autotuner
-    (:mod:`repro.exec.tune`) measures against. All three work axes must
-    clear a threshold: K/N feed the MXU contraction, and M must at least
+    """Static MXU-worthiness rule for the Pallas grouped matmul under
+    ``backend="auto"`` on the chip. All three work axes must clear a
+    threshold: K/N feed the MXU contraction, and M must at least
     fill one sublane tile — a tiny-M huge-K product (e.g. a (1, 4096) @
     (4096, 4096) head projection) is a matvec whose Pallas grid degenerates
     to one M-row of padded tiles, where ``jnp.matmul`` wins. The group

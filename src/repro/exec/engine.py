@@ -68,13 +68,6 @@ class CompileOptions:
                                  # at/above that severity. None reads the
                                  # REPRO_LINT env var (tests default it to
                                  # "error" in conftest.py; "off" elsewhere)
-    tune: str = "off"            # off|readonly|auto|force: measured
-                                 # (backend, block) selection per tunable
-                                 # step against the persisted tuning DB
-                                 # (repro.exec.tune; "readonly" never
-                                 # measures, "force" always re-measures)
-    tune_db: Optional[str] = None    # DB path; None -> results/tune/
-    tune_budget: int = 16        # max measured candidates per step
 
 
 class CompiledChain:
@@ -92,7 +85,6 @@ class CompiledChain:
         self.dispatch: Dict[str, str] = plan.dispatch
         self.options = options
         self.lint_report = None          # set by compile_chain when linted
-        self.tune_report = None          # set by compile_chain when tuned
         # mesh-aware mode: the ShardPlan plus the step list with the
         # tensor-parallel matmuls re-lowered to their column/row split
         self.shard_plan = shard_plan
@@ -476,7 +468,7 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
     ``engine_args_converted`` counts the values a call had to convert
     (NumPy arrays, Python scalars, lists); device arrays count nothing.
     ``compile_chain``'s own phases are annotated ``compile.partition``,
-    ``compile.plan``, ``compile.tune`` and ``compile.lint``.
+    ``compile.plan`` and ``compile.lint``.
 
     ``profile=True`` (or an enabled ``tracer=``) also records those spans
     into ``engine.tracer`` (a fresh ``repro.obs.trace.Tracer`` unless
@@ -494,15 +486,6 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
     ``lint=None`` (default) reads the ``REPRO_LINT`` env var ("off" when
     unset; conftest.py defaults it to "error" so every test-compiled
     chain is verified).
-
-    ``tune="auto"``: after heuristic planning, re-lower each tunable step
-    to the measured-fastest (backend, block) candidate — DB hits under
-    ``results/tune/`` are pure lookups, misses are measured on-device and
-    persisted (see :mod:`repro.exec.tune`). ``tune="readonly"`` applies
-    hits but never measures; ``tune="force"`` re-measures everything. The
-    decisions land in ``Step.meta['tuned']`` (audited by the
-    ``plan.tuned-contract`` lint rule), the per-group report on
-    ``engine.tune_report``.
     """
     import os
 
@@ -520,13 +503,6 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
     with _phase("compile.plan", spans):
         plan = plan_chain(fused, backend=backend, mxu_min=opts.mxu_min,
                           segments=opts.segments)
-    tune_report = None
-    if opts.tune != "off":
-        from .tune import tune_plan
-        with _phase("compile.tune", spans):
-            plan, tune_report = tune_plan(
-                fused, plan, mode=opts.tune, db_path=opts.tune_db,
-                budget=opts.tune_budget, backend=backend, tracer=tracer)
     shard_plan = None
     if mesh is not None and not mesh.empty:
         from .shardplan import derive_plan
@@ -538,7 +514,6 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
             plan.dispatch.setdefault(m, f"fused:{host}")
     eng = CompiledChain(chain, fused, report, parts, plan, opts,
                         shard_plan, tracer)
-    eng.tune_report = tune_report
     level = opts.lint if opts.lint is not None \
         else os.environ.get("REPRO_LINT", "off")
     if level and level != "off":

@@ -308,12 +308,10 @@ def lower_conv(node: GConv, plan) -> Callable:
     return fn
 
 
-def lower_conv_pallas(node: GConv, plan,
-                      block_o: Optional[int] = None) -> Optional[Callable]:
-    """NHWC Pallas spatial kernel for the plain 2-D case; None when the
-    geometry doesn't fit, and the caller dispatches to :func:`lower_conv`.
-    ``block_o`` threads the tuner's output-channel block through to
-    ``gconv_spatial`` (None keeps the kernel's ``BLOCK_O``).
+def lower_conv_pallas(node: GConv, plan) -> Optional[Callable]:
+    """NHWC Pallas spatial kernel for the plain 2-D case, at the kernel's
+    own ``BLOCK_O``; None when the geometry doesn't fit, and the caller
+    dispatches to :func:`lower_conv`.
 
     Eligibility, checked statically on every backend so the plan is the
     same in interpret mode and on the chip:
@@ -326,17 +324,14 @@ def lower_conv_pallas(node: GConv, plan,
         - stride != 1 — full-width AlexNet conv1 (32x227x227x3, 11x11,
           stride 4): "'vector.extract_strided_slice' op expected strides
           to be confined to [1, 2)";
-        - block_o < O and not a multiple of 128 — e.g. the tuner's
-          block_o=64 at AN conv3 (O=384): "The Pallas TPU lowering
+        - block_o < O and not a multiple of 128 — e.g. block_o=64 at
+          AN conv3 (O=384): "The Pallas TPU lowering
           currently requires that the last two dimensions of your block
           shape are divisible by 8 and 128 respectively";
         - double-buffered blocks over ``VMEM_BLOCK_BUDGET`` — a
           (32,112,112,64) 3x3 conv, and (32,80,80,64) 3x3: "Ran out of
-          memory in memory space vmem while allocating on stack".
-
-    The tuner asks this function per candidate ``block_o``, so its
-    candidates obey the same rule."""
-    from ..kernels.gconv_spatial import BLOCK_O, mosaic_refusal
+          memory in memory space vmem while allocating on stack"."""
+    from ..kernels.gconv_spatial import mosaic_refusal
 
     ch, windows, batch = plan
     dims = node.dims
@@ -348,9 +343,8 @@ def lower_conv_pallas(node: GConv, plan,
         return None
     if dh.padr != dh.pad or dw.padr != dw.pad:
         return None
-    block_o = BLOCK_O if block_o is None else block_o
     if mosaic_refusal(dh.nips, dw.nips, dch.in_size, dh.nks, dw.nks, dch.nop,
-                      stride=dh.stride, pad=dh.pad, block_o=block_o):
+                      stride=dh.stride, pad=dh.pad):
         return None
 
     from ..kernels.gconv_spatial import gconv_spatial
@@ -368,8 +362,7 @@ def lower_conv_pallas(node: GConv, plan,
         kb = jnp.transpose(k.astype(ct), [ch] + windows + batch)
         kb = kb.reshape(dch.nop, dch.nks, dh.nks, dw.nks)    # OIHW
         kb = jnp.transpose(kb, (2, 3, 1, 0))                 # HWIO
-        y = gconv_spatial(xb, kb, stride=dh.stride, pad=dh.pad,
-                          block_o=block_o)
+        y = gconv_spatial(xb, kb, stride=dh.stride, pad=dh.pad)
         y = jnp.transpose(y, (0, 3, 1, 2))
         y = y.reshape(tuple(b_sizes) + (dch.nop, dh.nopc, dw.nopc))
         y = jnp.transpose(y, np.argsort(perm)).reshape(node.out_shape)
@@ -554,10 +547,7 @@ def _tp_matmul(xb, kb, tp):
 
 
 def lower_grouped_matmul(node: GConv, plan, *, pallas: bool = False,
-                         tp=None, block=None) -> Callable:
-    """``block`` (Pallas path only): a tuner-materialized ``(bm, bn, bk)``
-    forwarded to ``gconv_matmul``; None keeps the kernel's static
-    defaults."""
+                         tp=None) -> Callable:
     g_ix, m_ix, c_ix = plan
     dims = node.dims
     G = int(np.prod([dims[i].ng for i in g_ix])) if g_ix else 1
@@ -609,10 +599,8 @@ def lower_grouped_matmul(node: GConv, plan, *, pallas: bool = False,
             epi_seq, epi_ops = epi if epi is not None else ((), ())
             epi_seq = tuple((nm, c, None if s is None else s + len(pro_ops))
                             for nm, c, s in epi_seq)
-            bkw = (dict(block_m=block[0], block_n=block[1],
-                        block_k=block[2]) if block is not None else {})
             y = gconv_matmul(xb, kb, prologue=pro_seq, epilogue=epi_seq,
-                             operands=pro_ops + epi_ops, **bkw)
+                             operands=pro_ops + epi_ops)
         elif tp is not None:
             y = _tp_matmul(xb, kb, tp)                       # (G, M, N)
         else:

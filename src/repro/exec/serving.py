@@ -128,7 +128,6 @@ class ServeEngine:
         self._batched_prefill_ok = (
             getattr(model, "prefill", None) is not None
             and not self.cfg.sliding_window)
-        self.tune_report = None          # set by tune()
 
     # -- state ----------------------------------------------------------
     def init_state(self):
@@ -351,153 +350,6 @@ class ServeEngine:
                                 "max_new": int(max_new)}):
                 return self._decode_single(params, prompt, max_new)
         return self._decode_single(params, prompt, max_new)
-
-    # -- measured variant selection (repro.exec.tune) -------------------
-    def tune(self, params, *, mode: str = "auto",
-             db_path: Optional[str] = None, budget: int = 8, seed: int = 0,
-             warmup: int = 1, repeats: int = 3) -> dict:
-        """Measured selection over the model's serving variants, sharing
-        the kernel autotuner's DB, modes and search engines
-        (:mod:`repro.exec.tune` / :mod:`repro.search`).
-
-        The serving programs jit the models' fused decode/prefill paths —
-        they are not chain-compiled — so the tunable points are the
-        model-level variants the config exposes, rebuilt via
-        ``models.api.build``:
-
-          * ``decode``  — ``perf_flags`` ± ``gqa_norepeat`` (only when KV
-                          heads actually repeat);
-          * ``prefill`` — ``attn_impl`` in chunked/naive (+ pallas off
-                          interpret mode).
-
-        Winners are applied in place (the engine rebuilds its jitted
-        programs on the winning config); decisions persist under
-        ``serve:``-prefixed DB keys so warm starts are pure lookups. The
-        cache layout is invariant under both knobs, so live slot state
-        survives an applied decision."""
-        import hashlib
-        import json as _json
-        from dataclasses import asdict, replace
-
-        from ..kernels.common import use_interpret
-        from ..models import api
-        from . import tune as T
-
-        if mode not in ("readonly", "auto", "force"):
-            raise ValueError(f"tune mode {mode!r}: want "
-                             f"readonly|auto|force")
-        cfg = self.cfg
-        report = dict(mode=mode, groups={}, applied={})
-        if getattr(cfg, "attn_impl", None) is None:
-            return report
-        db = T.load_db(db_path)
-        dev = T.device_key()
-        report.update(device=dev, db_path=db.path)
-        # config identity EXCLUDING the tuned knobs (else the key would
-        # chase the decision), plus the serving geometry
-        ident = asdict(cfg)
-        ident.pop("attn_impl", None)
-        base_flags = tuple(f for f in cfg.perf_flags
-                           if f != "gqa_norepeat")
-        ident["perf_flags"] = sorted(base_flags)
-        sig = hashlib.sha256(
-            _json.dumps(ident, sort_keys=True,
-                        default=str).encode()).hexdigest()[:16]
-        base_key = (f"{dev}|serve:{cfg.name}:{sig}"
-                    f":s{self.slots}x{self.max_len}")
-        choice = dict(attn_impl=cfg.attn_impl,
-                      gqa="gqa_norepeat" in cfg.perf_flags)
-
-        def variant(attn_impl=None, gqa=None):
-            flags = base_flags + (
-                ("gqa_norepeat",)
-                if (choice["gqa"] if gqa is None else gqa) else ())
-            return replace(cfg, perf_flags=flags,
-                           attn_impl=attn_impl or choice["attn_impl"])
-
-        groups = []
-        if cfg.n_kv_heads and cfg.n_heads > cfg.n_kv_heads:
-            groups.append(("decode", [("flags:-", dict(gqa=False)),
-                                      ("flags:gqa_norepeat",
-                                       dict(gqa=True))]))
-        if self._batched_prefill_ok:
-            impls = ["chunked", "naive"]
-            if not use_interpret():
-                impls.append("pallas")
-            groups.append(("prefill", [(f"attn:{i}", dict(attn_impl=i))
-                                       for i in impls]))
-        dirty = False
-        for gname, cands in groups:
-            cur = (("flags:gqa_norepeat" if choice["gqa"] else "flags:-")
-                   if gname == "decode" else f"attn:{choice['attn_impl']}")
-            ix = next((i for i, (t, _kw) in enumerate(cands) if t == cur),
-                      0)
-            cands.insert(0, cands.pop(ix))       # incumbent wins ties
-            key = f"{base_key}|{gname}"
-            entry = db.lookup(key) if mode != "force" else None
-            if entry is not None and (entry["backend"]
-                                      not in [t for t, _kw in cands]):
-                entry = None
-            if entry is None and mode == "readonly":
-                report["groups"][gname] = dict(backend=cur,
-                                               source="heuristic")
-                continue
-            if entry is None:
-                def _measure(i, _cands=cands, _g=gname):
-                    tag, kw = _cands[i]
-                    m = api.build(variant(**kw))
-                    if _g == "decode":
-                        fn = jax.jit(m.decode_step)
-                        st = m.serve_state_init(self.slots, self.max_len,
-                                                per_slot_pos=True)
-                        tok = jnp.zeros((self.slots, 1), jnp.int32)
-                        return T.measure_callable(
-                            fn, params, tok, st,
-                            warmup=warmup, repeats=repeats)
-                    lb = min(batch_bucket(MIN_LEN_BUCKET, MIN_LEN_BUCKET),
-                             self.max_len)
-                    fn = jax.jit(lambda p, t, l, _m=m:
-                                 _m.prefill(p, t, lengths=l))
-                    tok = jnp.zeros((1, lb), jnp.int32)
-                    ln = jnp.full((1,), lb, jnp.int32)
-                    return T.measure_callable(fn, params, tok, ln,
-                                              warmup=warmup,
-                                              repeats=repeats)
-
-                win, win_s, res = T.measured_select(
-                    len(cands), _measure, budget=budget, seed=seed)
-                entry = dict(backend=cands[win][0], block=None,
-                             latency_us=round(win_s * 1e6, 3),
-                             heuristic_backend=cur,
-                             n_candidates=len(cands),
-                             n_evals=res.n_evals, strategy=res.strategy)
-                db.record(key, entry)
-                dirty = True
-                src = "measured"
-            else:
-                src = "db"
-            tag = entry["backend"]
-            if gname == "decode":
-                choice["gqa"] = tag == "flags:gqa_norepeat"
-            else:
-                choice["attn_impl"] = tag.split(":", 1)[1]
-            report["groups"][gname] = dict(
-                backend=tag, source=src,
-                latency_us=entry["latency_us"])
-        if dirty:
-            T.save_db(db)
-        final = variant()
-        if (final.attn_impl, final.perf_flags) != (cfg.attn_impl,
-                                                   cfg.perf_flags):
-            # rebuild the jitted programs on the winning config; params
-            # and slot-state layouts are invariant under both knobs
-            self.__init__(api.build(final), slots=self.slots,
-                          max_len=self.max_len, mesh=self.mesh,
-                          tracer=self.tracer, chaos=self.chaos)
-            report["applied"] = dict(attn_impl=final.attn_impl,
-                                     perf_flags=list(final.perf_flags))
-        self.tune_report = report
-        return report
 
     def _decode_single(self, params, prompt, max_new):
         logits, rows, _n = self.prefill(params, [list(prompt)])

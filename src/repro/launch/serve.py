@@ -174,7 +174,7 @@ class Server:
                  bos_id: Optional[int] = 0, mesh=None, tracer=None,
                  resilience: Optional[ResilienceConfig] = None,
                  chaos=None, snapshot_dir: Optional[str] = None,
-                 snapshot_every: int = 0, tune: str = "off"):
+                 snapshot_every: int = 0):
         self.cfg = configs.get(arch, smoke=smoke)
         self.model = api.build(self.cfg)
         self.params = self.model.init(jax.random.PRNGKey(0))
@@ -203,12 +203,6 @@ class Server:
             chaos.observe(self.metrics, tracer)
         self.engine = ServeEngine(self.model, slots=slots, max_len=max_len,
                                   mesh=mesh, tracer=tracer, chaos=chaos)
-        # measured variant selection (repro.exec.tune): warm starts are
-        # pure DB lookups; "off" keeps the config exactly as built
-        self.tune_report = None
-        if tune and tune != "off":
-            self.tune_report = self.engine.tune(self.params, mode=tune)
-            self.model = self.engine.model
         self.params = self.engine.shard_params(self.params)
         self.cache = self.engine.init_state()
         self.slot_req: List[Optional[Request]] = [None] * slots
@@ -948,10 +942,6 @@ def main():
     ap.add_argument("--snapshot-dir", default=None,
                     help="write periodic serving snapshots here "
                          "(resume a crashed workload with Server.resume)")
-    ap.add_argument("--tune", default="off",
-                    choices=("off", "readonly", "auto", "force"),
-                    help="measured serving-variant selection against the "
-                         "results/tune DB (repro.exec.tune)")
     ap.add_argument("--snapshot-every", type=int, default=8,
                     help="ticks between snapshots (with --snapshot-dir)")
     args = ap.parse_args()
@@ -974,7 +964,7 @@ def main():
     srv = Server(args.arch, smoke=not args.full, slots=args.slots, mesh=mesh,
                  tracer=tracer, resilience=resilience, chaos=chaos,
                  snapshot_dir=args.snapshot_dir,
-                 snapshot_every=args.snapshot_every, tune=args.tune)
+                 snapshot_every=args.snapshot_every)
     rng = np.random.default_rng(0)
     reqs = [Request(rid=i,
                     prompt=rng.integers(0, srv.cfg.vocab,

@@ -175,22 +175,6 @@ def mut_unknown_step(ctx):
     ctx.plan.steps[0].name = "ghost"
 
 
-def mut_tuned_corrupt_block(ctx):
-    # a corrupted tuning-DB entry that slipped past quarantine and was
-    # applied: the tuned M-block wildly overshoots the node's M=8 axis
-    # (pick_block never-overshoot contract). Also demonstrates the R_MXU
-    # tuned-step exemption: only plan.tuned-contract / the block audit
-    # fire, not the heuristic mxu_min gate.
-    ctx.plan.dispatch["fc1"] = "matmul:pallas"
-    for st in ctx.plan.steps:
-        if st.name == "fc1":
-            st.backend = "matmul:pallas"
-            st.meta = dict(st.meta or {})
-            st.meta["tuned"] = dict(backend="matmul:pallas",
-                                    block=dict(m=8192, n=256, k=512),
-                                    source="db", group="fc1")
-
-
 # ---------------------------------------------------------------------------
 # shard-layer mutants (tamper ShardPlan / re-lowered step meta)
 # ---------------------------------------------------------------------------
@@ -250,8 +234,6 @@ MUTANTS: List[Tuple[str, str, Callable, Callable, str]] = [
      mut_step_disorder, "plan"),
     ("unknown_step", "plan.unknown-step", base_hot,
      mut_unknown_step, "plan"),
-    ("tuned_corrupt_block", "plan.tuned-contract", base_hot,
-     mut_tuned_corrupt_block, "plan"),
     ("tp_indivisible", "shard.tp-divisibility", base_col,
      mut_tp_indivisible, "shard"),
     ("missing_psum", "shard.missing-psum", base_row,

@@ -41,10 +41,6 @@ R_MXU = rule("plan.pallas-mxu-min", "plan", "error",
 R_BLOCK = rule("plan.pallas-block-contract", "plan", "error",
                "a Pallas matmul's block sizes violate the pick_block "
                "contract (or the node has no grouped-matmul geometry)")
-R_TUNED = rule("plan.tuned-contract", "plan", "error",
-               "a tuned (backend, block) decision in Step.meta is "
-               "inconsistent with the step or violates the block "
-               "contract for the node's geometry")
 R_COMPILE = rule("plan.compile-failed", "plan", "error",
                  "the chain failed to compile and no chain-layer "
                  "finding explains why")
@@ -161,13 +157,9 @@ def check_pallas_preconditions(ctx):
     """Pallas grouped-matmul steps: the node must have grouped-matmul
     geometry, auto-selection must respect the ``mxu_min`` gate (K/N feed
     the MXU; M must fill at least one sublane tile — the heuristic in
-    ``dispatch._prefer_pallas_matmul``), and the tile sizes — the tuner's
-    if the step carries a tuned decision, the static defaults otherwise —
-    must satisfy the ``pick_block`` contract for the node's (M, N, K).
-
-    TUNED steps are exempt from the ``mxu_min`` gate: that gate is the
-    no-DB *heuristic*; a measured selection that picked Pallas below it
-    did so on evidence, which is the point of the autotuner."""
+    ``dispatch._prefer_pallas_matmul``), and the kernel's static default
+    tile sizes must satisfy the ``pick_block`` contract for the node's
+    (M, N, K)."""
     fused = ctx.fused if ctx.fused is not None else ctx.source
     for st in ctx.plan.steps:
         if st.backend != "matmul:pallas":
@@ -183,8 +175,7 @@ def check_pallas_preconditions(ctx):
                         "geometry")
             continue
         _mplan, _G, M, N, K = geo
-        tuned = (st.meta or {}).get("tuned")
-        if (tuned is None and ctx.backend == "auto"
+        if (ctx.backend == "auto"
                 and (K < ctx.mxu_min or N < ctx.mxu_min or M < M_ALIGN)):
             yield make_finding(
                 ctx, R_MXU, node=st.name, M=M, K=K, N=N,
@@ -192,94 +183,13 @@ def check_pallas_preconditions(ctx):
                 message=f"auto-dispatched to Pallas with M={M} K={K} "
                         f"N={N} below the mxu_min={ctx.mxu_min} / "
                         f"M_ALIGN={M_ALIGN} gate")
-        block = (tuned or {}).get("block") or {}
         for axis, n, target, align in (("M", M, BLOCK_M, M_ALIGN),
                                        ("N", N, BLOCK_N, N_ALIGN),
                                        ("K", K, BLOCK_K, K_ALIGN)):
-            b = block.get(axis.lower())
-            if b is None:
-                b = min(target, pick_block(n, target, align))
+            b = min(target, pick_block(n, target, align))
             if not block_contract_ok(n, b, align):
                 yield make_finding(
                     ctx, R_BLOCK, node=st.name, axis=axis, n=n, block=b,
-                    align=align, tuned=tuned is not None,
+                    align=align,
                     message=f"block {b} for {axis}={n} violates the "
                             f"pick_block contract (align {align})")
-
-
-@lint_pass("plan")
-def check_tuned_meta(ctx):
-    """Audit tuned (backend, block) decisions declared in ``Step.meta``
-    (:mod:`repro.exec.tune`): the meta must agree with the step it rides
-    on (same backend tag, same group/step name, a live fused-chain GCONV),
-    the block must belong to the backend's vocabulary, and a Pallas
-    matmul block must satisfy ``block_contract_ok`` against the node's
-    actual (M, N, K) — so a corrupted or stale tuning-DB entry that
-    somehow reached a plan is caught before it executes."""
-    fused = ctx.fused if ctx.fused is not None else ctx.source
-    tunable = ("matmul:jnp", "matmul:pallas", "conv:lax", "conv:pallas",
-               "einsum")
-    for st in ctx.plan.steps:
-        tuned = (st.meta or {}).get("tuned")
-        if tuned is None:
-            continue
-        if not isinstance(tuned, dict):
-            yield make_finding(ctx, R_TUNED, node=st.name,
-                               message="tuned meta is not a mapping")
-            continue
-        tag = tuned.get("backend")
-        if tag not in tunable:
-            yield make_finding(
-                ctx, R_TUNED, node=st.name, backend=tag,
-                message=f"tuned backend {tag!r} is not a tunable tag")
-        elif tag != st.backend:
-            yield make_finding(
-                ctx, R_TUNED, node=st.name, backend=tag,
-                message=f"tuned backend {tag!r} disagrees with the "
-                        f"step's backend {st.backend!r}")
-        if tuned.get("group") not in (None, st.name):
-            yield make_finding(
-                ctx, R_TUNED, node=st.name, group=tuned.get("group"),
-                message=f"tuned group {tuned.get('group')!r} names a "
-                        f"different step")
-        node = fused.nodes.get(st.name)
-        if not isinstance(node, GConv):
-            yield make_finding(
-                ctx, R_TUNED, node=st.name,
-                message="tuned decision on a non-GCONV step")
-            continue
-        block = tuned.get("block")
-        if block is None:
-            continue
-        if st.backend == "matmul:pallas":
-            geo = _matmul_geometry(node, fused)
-            if geo is None or sorted(block) != ["k", "m", "n"]:
-                yield make_finding(
-                    ctx, R_TUNED, node=st.name, block=block,
-                    message="tuned matmul block without (m, n, k) axes "
-                            "or grouped-matmul geometry")
-                continue
-            _mplan, _G, M, N, K = geo
-            for axis, n, align in (("m", M, M_ALIGN), ("n", N, N_ALIGN),
-                                   ("k", K, K_ALIGN)):
-                b = block[axis]
-                if not (isinstance(b, int)
-                        and block_contract_ok(n, b, align)):
-                    yield make_finding(
-                        ctx, R_TUNED, node=st.name, axis=axis, n=n,
-                        block=b, align=align,
-                        message=f"tuned block {b!r} for {axis.upper()}="
-                                f"{n} violates the pick_block contract "
-                                f"(align {align})")
-        elif st.backend == "conv:pallas":
-            o = block.get("o") if sorted(block) == ["o"] else None
-            if not (isinstance(o, int) and o >= 1):
-                yield make_finding(
-                    ctx, R_TUNED, node=st.name, block=block,
-                    message=f"tuned conv block {block!r} is not a "
-                            f"positive {{'o': int}}")
-        else:
-            yield make_finding(
-                ctx, R_TUNED, node=st.name, block=block,
-                message=f"tuned block on a blockless backend "
-                        f"{st.backend!r}")

@@ -27,7 +27,11 @@ from .route import RoutedChain, Task
 from .stats import JobStats, SystemReport, UnitStats
 from .system import SystemSpec
 
-_EPS = 1e-9
+def _tol(scale: float) -> float:
+    """The engine's one tolerance, scaled to the magnitude it guards: 1e-9
+    up to 1, relative above (cycle counts reach 1e9, where a fixed 1e-9
+    is below one ulp)."""
+    return 1e-9 * max(1.0, abs(scale))
 
 
 @dataclass
@@ -65,9 +69,13 @@ class _UnitState:
 
 
 def _demand(task: Task, link_bw: float) -> float:
+    """Words/cycle while in service. A rate within tolerance of zero is
+    zero: the arbiter would grant it nothing, so the task would never
+    progress. Its words are booked at retirement instead (``complete``)."""
     if task.work <= 0 or task.bus_words <= 0:
         return 0.0
-    return min(task.bus_words / task.work, link_bw)
+    d = task.bus_words / task.work
+    return 0.0 if d <= _tol(link_bw) else min(d, link_bw)
 
 
 def simulate_system(jobs: Sequence[ChainJob],
@@ -117,8 +125,9 @@ def simulate_system(jobs: Sequence[ChainJob],
         st.energy += r.task.energy
         # conservation true-up: the fluid flow integrates demand over the
         # *credited* service window; the words hidden under the handoff
-        # overlap (and any fp residue) still crossed the interconnect —
-        # book them at retirement so injected == offered exactly
+        # overlap, those of a negligible demand (``_demand``) and any fp
+        # residue still crossed the interconnect — book them at
+        # retirement so injected == offered exactly
         shortfall = r.task.bus_words - r.demand * r.work0
         if shortfall > 0.0:
             st.injected_words += shortfall
@@ -146,7 +155,7 @@ def simulate_system(jobs: Sequence[ChainJob],
                 if us.running is not None or not us.queue:
                     continue
                 ready, _, job_idx, task_idx = us.queue[0]
-                if ready > now + _EPS:
+                if ready > now + _tol(now):
                     continue
                 us.queue.pop(0)
                 task = jobs[job_idx].tasks[task_idx]
@@ -161,7 +170,7 @@ def simulate_system(jobs: Sequence[ChainJob],
                                       task=task, remaining=work, work0=work,
                                       demand=_demand(task, us.link_bw))
                 progressed = True
-                if work <= _EPS:
+                if work <= _tol(task.work):
                     complete(us, us.running)
 
     # admit nothing yet; the loop advances time across arrivals,
@@ -174,7 +183,7 @@ def simulate_system(jobs: Sequence[ChainJob],
             raise RuntimeError("syssim: event-loop failed to converge "
                                f"({remaining_tasks} tasks stranded)")
         while (next_arrival < len(arrivals)
-               and jobs[arrivals[next_arrival]].arrival <= now + _EPS):
+               and jobs[arrivals[next_arrival]].arrival <= now + _tol(now)):
             enqueue(arrivals[next_arrival], 0,
                     jobs[arrivals[next_arrival]].arrival)
             next_arrival += 1
@@ -218,7 +227,8 @@ def simulate_system(jobs: Sequence[ChainJob],
         now += dt
 
         for n, us in list(active.items()):
-            if us.running is not None and us.running.remaining <= _EPS:
+            if (us.running is not None
+                    and us.running.remaining <= _tol(us.running.work0)):
                 complete(us, us.running)
 
     makespan = max([now] + [j.finish for j in job_stats]) if job_stats \

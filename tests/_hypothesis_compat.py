@@ -4,7 +4,8 @@
 suite must still collect and run its non-property tests without it. Property
 tests import through this shim:
 
-    from _hypothesis_compat import HAVE_HYPOTHESIS, assume, given, settings, st
+    from _hypothesis_compat import (HAVE_HYPOTHESIS, assume, example, given,
+                                    settings, st)
 
 With hypothesis installed this re-exports the real API unchanged. Without
 it, ``@given(...)`` turns each property test into an individually-skipped
@@ -14,7 +15,7 @@ its whole module.
 import pytest
 
 try:
-    from hypothesis import assume, given, settings
+    from hypothesis import assume, example, given, settings
     from hypothesis import strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:
@@ -28,6 +29,9 @@ except ImportError:
             return fn
         return deco
 
+    def example(*_args, **_kwargs):
+        return lambda fn: fn
+
     def assume(_condition):
         return True
 
@@ -40,4 +44,5 @@ except ImportError:
 
     st = _AnyStrategy()
 
-__all__ = ["HAVE_HYPOTHESIS", "assume", "given", "settings", "st"]
+__all__ = ["HAVE_HYPOTHESIS", "assume", "example", "given", "settings",
+           "st"]

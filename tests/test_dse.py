@@ -14,7 +14,7 @@ from repro.core.gconv import DimSpec, GConv
 from repro.core.mapping import Entry, Mapping, MappingError, map_gconv
 from repro.dse import (Evaluator, EvalRecord, SpecSpace, baseline_points,
                        load_suite, pareto_front, search_mapping)
-from repro.dse.search import STRATEGIES
+from repro.dse.strategies import STRATEGIES
 
 
 @pytest.fixture(scope="module")

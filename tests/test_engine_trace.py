@@ -149,13 +149,12 @@ def test_build_counters_fire_once_per_program():
                              program="bucket=4") == 0
 
 
-def test_compile_chain_spans_its_phases(tmp_path):
+def test_compile_chain_spans_its_phases():
     chain, _inputs, _params = _case("MN")
-    eng = compile_chain(chain, profile=True, lint="error", tune="readonly",
-                        tune_db=str(tmp_path / "tune.json"))
+    eng = compile_chain(chain, profile=True, lint="error")
     assert [e["name"] for e in eng.tracer.events
             if e["type"] == "span" and e["cat"] == "compile"] == [
-        "compile.partition", "compile.plan", "compile.tune", "compile.lint"]
+        "compile.partition", "compile.plan", "compile.lint"]
 
 
 def test_nested_build_events_count_once():

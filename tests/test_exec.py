@@ -440,3 +440,79 @@ def test_use_interpret_env_override(monkeypatch):
     assert common.use_interpret() is True
     monkeypatch.delenv("REPRO_FORCE_INTERPRET")
     assert common.use_interpret() is common._backend_wants_interpret()
+
+
+# ---------------------------------------------------------------------------
+# the chip-side kernel choice: dispatch_gconv's static rule
+# ---------------------------------------------------------------------------
+def _matmul_plan(ch, name):
+    node = ch.nodes[name]
+    classes = low.dim_classes(node)
+    kshape = tuple(ch.shape_of(node.kernel))
+    return node, low.match_grouped_matmul(node, classes, kshape)
+
+
+def test_prefer_pallas_rejects_tiny_m_huge_k(monkeypatch):
+    """(1, 4096) @ (4096, 4096) is a matvec: its Pallas grid degenerates
+    to one padded M-row, so the heuristic must keep jnp even though K
+    and N dwarf mxu_min (the pre-fix code only looked at K/N)."""
+    from repro.exec.dispatch import _prefer_pallas_matmul
+    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
+    ch = Chain("matvec")
+    x = ch.add_input("x", (1, 4096))
+    ch.mark_output(L.fc(ch, x, out_f=4096, name="fc1"))
+    node, plan = _matmul_plan(ch, "fc1")
+    assert plan is not None
+    assert not _prefer_pallas_matmul("auto", 128, plan, node)
+
+
+def test_prefer_pallas_accepts_aligned_m(monkeypatch):
+    from repro.exec.dispatch import _prefer_pallas_matmul
+    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
+    ch = Chain("fat")
+    x = ch.add_input("x", (8, 512))
+    ch.mark_output(L.fc(ch, x, out_f=512, name="fc1"))
+    node, plan = _matmul_plan(ch, "fc1")
+    assert _prefer_pallas_matmul("auto", 128, plan, node)
+    # forced pallas bypasses the heuristic; small K/N still fails auto
+    assert _prefer_pallas_matmul("pallas", 128, plan, node)
+    ch2 = Chain("thin")
+    x2 = ch2.add_input("x", (8, 64))
+    ch2.mark_output(L.fc(ch2, x2, out_f=64, name="fc1"))
+    node2, plan2 = _matmul_plan(ch2, "fc1")
+    assert not _prefer_pallas_matmul("auto", 128, plan2, node2)
+
+
+# Each full-width zoo net's backend histogram under backend="auto" on the
+# chip. A change to the rule (dispatch_gconv, _prefer_pallas_matmul, the
+# lowerings' refusal rules) that moves a step shows here and must update
+# these numbers on purpose.
+CHIP_PLAN = {
+    "AN": {"conv:lax": 4, "conv:pallas": 1, "fused": 14, "matmul:pallas": 3,
+           "movement": 1, "reduce": 5, "segment:softmax": 1},
+    "GLN": {"concat": 9, "conv:lax": 1, "conv:pallas": 19,
+            "elementwise": 37, "fused": 26, "matmul:jnp": 21,
+            "matmul:pallas": 17, "movement": 1, "reduce": 16,
+            "segment:softmax": 1},
+    "MN": {"conv:lax": 1, "dwconv:pallas-vpu": 13, "fused": 111,
+           "matmul:jnp": 2, "matmul:pallas": 12, "movement": 1,
+           "reduce": 55, "segment:softmax": 1},
+    "DN": {"concat": 58, "conv:lax": 1, "conv:pallas": 58, "fused": 487,
+           "matmul:jnp": 2, "matmul:pallas": 60, "movement": 1,
+           "reduce": 247, "segment:softmax": 1},
+    "ZFFR": {"conv:lax": 2, "conv:pallas": 4, "elementwise": 1, "fused": 16,
+             "matmul:jnp": 4, "matmul:pallas": 2, "movement": 4,
+             "reduce": 5, "segment:softmax": 2},
+    "C3D": {"conv:lax": 8, "fused": 15, "matmul:pallas": 3, "movement": 1,
+            "reduce": 5, "segment:softmax": 1},
+    "CapNN": {"conv:lax": 1, "conv:pallas": 1, "elementwise": 14,
+              "fused": 12, "matmul:jnp": 6, "movement": 13, "reduce": 8,
+              "segment:softmax": 1},
+}
+
+
+@pytest.mark.parametrize("name", list(cnn.ZOO))
+def test_chip_rule_plan(name, monkeypatch):
+    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
+    eng = compile_chain(cnn.build(name), backend="auto", lint="off")
+    assert eng.backend_histogram() == CHIP_PLAN[name]

@@ -2,7 +2,7 @@
 heterogeneous overlap, serve-trace replay, and the arbitration
 invariants (word conservation, monotone latency under contention)."""
 import pytest
-from _hypothesis_compat import given, settings, st
+from _hypothesis_compat import example, given, settings, st
 
 from repro.core import accelerators as acc
 from repro.sim.validate import DEFAULT_ACCELS, DRIFT_TOL
@@ -198,6 +198,7 @@ task_strategy = st.lists(
 @given(st.lists(task_strategy, min_size=1, max_size=3),
        st.floats(min_value=0.5, max_value=32.0, allow_nan=False))
 @settings(max_examples=60, deadline=None)
+@example(jobs_params=[[(1.0, 3.467560678637506e-296)]], bw=1.0)
 def test_engine_conserves_words_under_contention(jobs_params, bw):
     arrivals = [3.0 * i for i in range(len(jobs_params))]
     jobs = _toy_jobs(jobs_params, arrivals)
@@ -218,6 +219,7 @@ def test_engine_conserves_words_under_contention(jobs_params, bw):
                                 allow_nan=False),
        st.floats(min_value=1.1, max_value=8.0, allow_nan=False))
 @settings(max_examples=60, deadline=None)
+@example(tasks=[(1.0, 1.7710721796340165e-189)], bw=1.0, squeeze=2.0)
 def test_engine_latency_monotone_in_capacity(tasks, bw, squeeze):
     wide = simulate_system(_toy_jobs([tasks], [0.0]), _toy_system(bw))
     narrow = simulate_system(_toy_jobs([tasks], [0.0]),
@@ -231,6 +233,8 @@ def test_engine_latency_monotone_in_capacity(tasks, bw, squeeze):
 @given(st.lists(task_strategy, min_size=1, max_size=2), task_strategy,
        st.floats(min_value=0.5, max_value=16.0, allow_nan=False))
 @settings(max_examples=40, deadline=None)
+@example(base_jobs=[[(1.0, 4.271146188712234e-43)]], extra=[(1.0, 0.0)],
+         bw=1.0)
 def test_engine_latency_monotone_in_load(base_jobs, extra, bw):
     arrivals = [0.0] * len(base_jobs)
     before = simulate_system(_toy_jobs(base_jobs, arrivals),
