@@ -104,7 +104,8 @@ class CompiledChain:
         # ``tracer`` while it is enabled; ``metrics`` holds each program's
         # build counters and the phase seconds of timed calls, and, counted
         # once here, the reduce steps by how their window dims fold
-        # (lowering.window_fold) and the depthwise convs by backend
+        # (lowering.window_fold), the depthwise convs by backend and the
+        # spatial-kernel convs whose output width the kernel pads
         self.tracer = tracer
         self.metrics = Metrics()
         folds = [window_fold(chain.nodes[s.name]) for s in self.steps
@@ -118,6 +119,9 @@ class CompiledChain:
                                          "conv:lax"}):
             self.metrics.counter("engine_depthwise_steps",
                                  backend=backend).inc(dw.count(backend))
+        self.metrics.counter("engine_spatial_width_padded_steps").inc(
+            sum(_spatial_width_padded(chain, s.name) for s in self.steps
+                if s.backend == "conv:pallas"))
         self._builds = compiles.install()
         # what a call takes from the caller's dicts, and how many of those
         # values were not yet jax.Arrays (counted only when some were)
@@ -418,14 +422,29 @@ def hlo_op_steps(text: str, steps) -> Dict[str, str]:
     return out
 
 
-def _is_depthwise(chain: Chain, name: str) -> bool:
-    """Is the step ``name`` a depthwise conv (``lowering.is_depthwise``)?"""
+def _conv_plan(chain: Chain, name: str):
+    """``lowering.match_conv``'s plan of the step ``name``, or None when it
+    is not a conv."""
     node = chain.nodes.get(name)
     if not isinstance(node, GConv) or node.kernel is None:
-        return False
-    plan = match_conv(node, dim_classes(node),
+        return None
+    return match_conv(node, dim_classes(node),
                       tuple(chain.shape_of(node.kernel)))
-    return plan is not None and is_depthwise(node, plan)
+
+
+def _is_depthwise(chain: Chain, name: str) -> bool:
+    """Is the step ``name`` a depthwise conv (``lowering.is_depthwise``)?"""
+    plan = _conv_plan(chain, name)
+    return plan is not None and is_depthwise(chain.nodes[name], plan)
+
+
+def _spatial_width_padded(chain: Chain, name: str) -> bool:
+    """Does ``gconv_spatial`` compute the ``conv:pallas`` step ``name``
+    wider than its output (``gconv_spatial.block_width``)?"""
+    from ..kernels.gconv_spatial import block_width
+    _, windows, _ = _conv_plan(chain, name)
+    ow = chain.nodes[name].dims[windows[1]].nopc      # (H, W) windows
+    return block_width(ow) != ow
 
 
 @contextmanager

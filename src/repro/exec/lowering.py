@@ -330,7 +330,11 @@ def lower_conv_pallas(node: GConv, plan) -> Optional[Callable]:
           shape are divisible by 8 and 128 respectively";
         - double-buffered blocks over ``VMEM_BLOCK_BUDGET`` — a
           (32,112,112,64) 3x3 conv, and (32,80,80,64) 3x3: "Ran out of
-          memory in memory space vmem while allocating on stack"."""
+          memory in memory space vmem while allocating on stack". The
+          input block is counted as the kernel holds it: one padded
+          image whose W axis is widened by ``block_width(ow) - ow``
+          zero columns, so that every tap reads a window whose width
+          is a multiple of the 8-row sublane tile."""
     from ..kernels.gconv_spatial import mosaic_refusal
 
     ch, windows, batch = plan

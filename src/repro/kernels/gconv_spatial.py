@@ -8,15 +8,27 @@ contractions. HBM traffic is exactly the unique input footprint — the
 Table-3 input-movement formula, not the im2col-replicated one.
 
 Blocking: grid (B, O-tiles). Each step holds one padded input image
-(H+2p, W+2p, C) and one kernel slice (KH, KW, C, bo) in VMEM and produces the
-(OH, OW, bo) output block. The static KH x KW Python loop unrolls into
-MXU dots over the same VMEM block — this is the Eyeriss overlap-reuse
-primitive (paper Fig. 8) re-derived for a vector/matrix memory hierarchy.
+(H+2p, W+2p+OWp-OW, C) and one kernel slice (KH, KW, C, bo) in VMEM and
+produces the (OH, OW, bo) output block. The static KH x KW Python loop
+unrolls into MXU dots over the same VMEM block — this is the Eyeriss
+overlap-reuse primitive (paper Fig. 8) re-derived for a vector/matrix
+memory hierarchy.
+
+Each tap reads its (OH, OWp, C) window straight from the input ref, where
+OWp = :func:`block_width` (OW) is the output width rounded up to the
+8-row sublane tile: the W offset is a sublane shift, the H offset a
+leading-axis slice, and the reshape to (OH*OWp, C) for the dot only
+merges whole tiles. At OW % 8 != 0 that reshape would otherwise relayout
+every tap's window. The input's W axis takes OWp-OW extra zero columns on
+the right, in the same ``jnp.pad`` as the conv padding; they feed only the
+discarded outputs OW..OWp-1, and the store keeps the first OW columns, so
+every kept output sums the same products in the same order.
 
 Nothing splits a feature map into halo tiles: the whole padded image is one
 block, so Mosaic compiles the kernel only for stride 1 and for geometries
-whose blocks fit :data:`VMEM_BLOCK_BUDGET`. :func:`mosaic_refusal` states
-that rule; ``exec.lowering.lower_conv_pallas`` applies it on every backend
+whose blocks fit :data:`VMEM_BLOCK_BUDGET`, the input block counted at its
+widened width. :func:`mosaic_refusal` states that rule;
+``exec.lowering.lower_conv_pallas`` applies it on every backend
 (interpret mode included, so CPU and TPU plans agree) and sends refused
 convs to ``lax.conv_general_dilated``.
 """
@@ -33,6 +45,7 @@ from jax.experimental import pallas as pl
 from .common import cdiv, round_up, use_interpret
 
 BLOCK_O = 128                   # default output-channel block
+SUBLANE = 8                     # rows of an f32 (8, 128) tile
 O_ALIGN = 128                   # a block narrower than O must fill lanes
 # Double-buffered f32 input + weight + output blocks, each padded to the
 # (8, 128) tile. Compiling for a TPU v5e, every zoo geometry at or under
@@ -47,10 +60,17 @@ def vmem_block_bytes(hp: int, wp: int, c: int, kh: int, kw: int,
     (hp, wp, c), one weight slice (kh, kw, c, bo), one output block
     (oh, ow, bo), all f32 and padded to the (8, 128) sublane x lane tile."""
     def tile(*shape):
-        return (4 * math.prod(shape[:-2]) * round_up(shape[-2], 8)
+        return (4 * math.prod(shape[:-2]) * round_up(shape[-2], SUBLANE)
                 * round_up(shape[-1], 128))
 
     return 2 * (tile(hp, wp, c) + tile(kh, kw, c, bo) + tile(oh, ow, bo))
+
+
+def block_width(ow: int) -> int:
+    """Output columns one grid step computes for an output ``ow`` wide:
+    ``ow`` rounded up to the sublane tile, so that each tap's window
+    reshapes to the dot's rows without a relayout."""
+    return round_up(ow, SUBLANE)
 
 
 def mosaic_refusal(h: int, w: int, c: int, kh: int, kw: int, o: int, *,
@@ -66,7 +86,9 @@ def mosaic_refusal(h: int, w: int, c: int, kh: int, kw: int, o: int, *,
         return (f"block_o {bo} < O={o} is not a multiple of {O_ALIGN} "
                 f"(block shape must be (8, 128)-divisible or whole)")
     hp, wp = h + 2 * pad, w + 2 * pad
-    need = vmem_block_bytes(hp, wp, c, kh, kw, hp - kh + 1, wp - kw + 1, bo)
+    oh, ow = hp - kh + 1, wp - kw + 1
+    need = vmem_block_bytes(hp, wp + block_width(ow) - ow, c, kh, kw,
+                            oh, ow, bo)
     if need > VMEM_BLOCK_BUDGET:
         return (f"blocks need {need} B of VMEM > budget "
                 f"{VMEM_BLOCK_BUDGET} B (whole padded image per block)")
@@ -74,22 +96,22 @@ def mosaic_refusal(h: int, w: int, c: int, kh: int, kw: int, o: int, *,
 
 
 def _kernel(x_ref, w_ref, o_ref, *, kh: int, kw: int, stride: int,
-            oh: int, ow: int):
-    x = x_ref[0].astype(jnp.float32)            # (H+2p, W+2p, C)
-    C = x.shape[-1]
-    acc = jnp.zeros((oh * ow, o_ref.shape[-1]), jnp.float32)
+            oh: int, ow: int, owp: int):
+    C = x_ref.shape[-1]
+    acc = jnp.zeros((oh * owp, o_ref.shape[-1]), jnp.float32)
     for i in range(kh):                          # unrolled taps: overlap-reuse
         for j in range(kw):
-            win = jax.lax.slice(
-                x, (i, j, 0),
-                (i + (oh - 1) * stride + 1, j + (ow - 1) * stride + 1, C),
-                (stride, stride, 1))             # (oh, ow, C) shifted view
+            win = x_ref[0, pl.ds(i, (oh - 1) * stride + 1),
+                        pl.ds(j, (owp - 1) * stride + 1), :]
+            if stride != 1:
+                win = win[::stride, ::stride]
+            win = win.astype(jnp.float32)        # (oh, owp, C) shifted view
             wij = w_ref[i, j].astype(jnp.float32)     # (C, bo)
             acc += jax.lax.dot_general(
-                win.reshape(oh * ow, C), wij,
+                win.reshape(oh * owp, C), wij,
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-    o_ref[0] = acc.reshape(oh, ow, -1)
+    o_ref[0] = acc.reshape(oh, owp, -1)[:, :ow]
 
 
 def gconv_spatial(x: jax.Array, w: jax.Array, *, stride: int = 1,
@@ -113,9 +135,11 @@ def _gconv_spatial(x, w, *, stride, pad, block_o, interpret):
     assert C == C2
     oh = (H + 2 * pad - KH) // stride + 1
     ow = (W + 2 * pad - KW) // stride + 1
-    if pad:
-        x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-    Hp, Wp = H + 2 * pad, W + 2 * pad
+    owp = block_width(ow)
+    extra = (owp - ow) * stride          # zero columns for outputs ow..owp-1
+    if pad or extra:
+        x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad + extra), (0, 0)))
+    Hp, Wp = H + 2 * pad, W + 2 * pad + extra
     bo = min(block_o, O)
     Op = cdiv(O, bo) * bo
     if Op != O:          # boundary blocks must be well-defined: zero-pad O
@@ -123,7 +147,8 @@ def _gconv_spatial(x, w, *, stride, pad, block_o, interpret):
     grid = (B, Op // bo)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, kh=KH, kw=KW, stride=stride, oh=oh, ow=ow),
+        functools.partial(_kernel, kh=KH, kw=KW, stride=stride, oh=oh, ow=ow,
+                          owp=owp),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, Hp, Wp, C), lambda b, o: (b, 0, 0, 0)),

@@ -516,3 +516,15 @@ def test_chip_rule_plan(name, monkeypatch):
     monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
     eng = compile_chain(cnn.build(name), backend="auto", lint="off")
     assert eng.backend_histogram() == CHIP_PLAN[name]
+
+
+@pytest.mark.parametrize("name,want", [("GLN", 18), ("MN", 0)])
+def test_engine_counts_spatial_width_padded_steps(name, want, monkeypatch):
+    """At the chip plan every GoogLeNet ``conv:pallas`` step but conv2
+    (output width 56) has an output width off the sublane tile (28, 14 or
+    7) that ``gconv_spatial`` pads; MobileNet plans no such step."""
+    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
+    eng = compile_chain(cnn.build(name), backend="auto", lint="off")
+    got = eng.metrics.to_dict()["metrics"][
+        "engine_spatial_width_padded_steps"]["series"][0]["value"]
+    assert got == want

@@ -81,6 +81,12 @@ def test_gconv_matmul_grouped_epilogue_operand():
 @pytest.mark.parametrize("b,h,c,o,kk,stride,pad", [
     (1, 8, 8, 8, 3, 1, 1), (2, 12, 4, 8, 3, 2, 1), (1, 11, 3, 5, 5, 2, 2),
     (2, 9, 16, 32, 1, 1, 0),
+    # output widths off the 8-row sublane tile (the block computes them
+    # padded to 8 and 16), one O over 128 that is not a multiple of it,
+    # and an aligned control
+    (2, 7, 16, 208, 3, 1, 1), (1, 14, 8, 32, 3, 1, 1),
+    (1, 7, 8, 16, 5, 1, 2), (2, 14, 8, 16, 5, 1, 2),
+    (1, 16, 8, 16, 3, 1, 1),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gconv_spatial_sweep(b, h, c, o, kk, stride, pad, dtype):

@@ -23,7 +23,8 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gconv_depthwise import gconv_depthwise
 from repro.kernels.gconv_depthwise import mosaic_refusal as dw_refusal
 from repro.kernels.gconv_matmul import gconv_matmul
-from repro.kernels.gconv_spatial import gconv_spatial, mosaic_refusal
+from repro.kernels.gconv_spatial import (VMEM_BLOCK_BUDGET, gconv_spatial,
+                                         mosaic_refusal, vmem_block_bytes)
 from repro.models import cnn
 
 
@@ -79,9 +80,14 @@ def test_gconv_matmul_refused_in_a_sharded_program(topo):
 
 
 # (B, H, W, C, K, O, pad): the unpadded maps of GoogLeNet conv2 and a
-# DenseNet-121 dense-block 3x3 conv, both 58x58 once padded
+# DenseNet-121 dense-block 3x3 conv, both 58x58 once padded, and three
+# GoogLeNet convs whose output widths (28, 14, 7) the kernel pads to the
+# sublane tile
 SPATIAL = {"GLN.conv2": (32, 56, 56, 64, 3, 192, 1),
-           "DN.b0.conv3x3": (32, 56, 56, 128, 3, 32, 1)}
+           "DN.b0.conv3x3": (32, 56, 56, 128, 3, 32, 1),
+           "GLN.3b.3x3": (32, 28, 28, 128, 3, 192, 1),
+           "GLN.4e.5x5": (32, 14, 14, 32, 5, 128, 2),
+           "GLN.5b.3x3": (32, 7, 7, 192, 3, 384, 1)}
 
 
 @pytest.mark.parametrize("geom", list(SPATIAL.values()), ids=list(SPATIAL))
@@ -90,6 +96,17 @@ def test_gconv_spatial_compiles(one_chip, geom):
     assert mosaic_refusal(H, W, C, K, K, O, stride=1, pad=pad) is None
     _compile(functools.partial(gconv_spatial, pad=pad, interpret=False),
              one_chip, (B, H, W, C), (K, K, C, O))
+
+
+def test_vmem_rule_counts_the_widened_input_block():
+    """A 14x14 3x3 conv (pad 1) computes 16 output columns, so its input
+    block is 18 wide, not 16. At C = 704 that widening alone takes the
+    blocks over the budget."""
+    need = vmem_block_bytes(16, 18, 704, 3, 3, 14, 14, 128)
+    assert vmem_block_bytes(16, 16, 704, 3, 3, 14, 14, 128) \
+        <= VMEM_BLOCK_BUDGET < need
+    why = mosaic_refusal(14, 14, 704, 3, 3, 128, stride=1, pad=1)
+    assert f"blocks need {need} B" in why
 
 
 # (B, H, W, C, stride) of MobileNet's depthwise convs at b32: the largest
