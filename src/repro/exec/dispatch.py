@@ -84,10 +84,19 @@ def _prefer_pallas_matmul(backend: str, mxu_min: int, plan, node) -> bool:
     return M >= M_ALIGN and K >= mxu_min and N >= mxu_min
 
 
+def is_max_pool(node) -> bool:
+    """A max over a sliding window (no kernel, ``reduce == "max"``, a
+    window dim): a max pool."""
+    return (isinstance(node, GConv) and node.main == "none"
+            and node.reduce == "max" and low.WINDOW in low.dim_classes(node))
+
+
 def dispatch_gconv(node: GConv, k_shape: Optional[Tuple[int, ...]],
-                   backend: str = "auto",
-                   mxu_min: int = 128) -> Tuple[str, Callable]:
-    """Pick (backend_tag, fn(x, k, lookup)) for one GCONV node."""
+                   backend: str = "auto", mxu_min: int = 128, *,
+                   after_pool: bool = False) -> Tuple[str, Callable]:
+    """Pick (backend_tag, fn(x, k, lookup)) for one GCONV node;
+    ``after_pool``: a max pool produces its input (:func:`is_max_pool`),
+    which a grouped conv needs to take ``gconv_spatial``."""
     classes = low.dim_classes(node)
     if all(c == low.BCAST for c in classes):
         return "elementwise", low.lower_elementwise(node)
@@ -116,7 +125,7 @@ def dispatch_gconv(node: GConv, k_shape: Optional[Tuple[int, ...]],
                 if fn is not None:
                     return "dwconv:pallas-vpu", fn
             elif pallas:
-                fn = low.lower_conv_pallas(node, cplan)
+                fn = low.lower_conv_pallas(node, cplan, after_pool=after_pool)
                 if fn is not None:
                     return "conv:pallas", fn
             return "conv:lax", low.lower_conv(node, cplan)
@@ -475,7 +484,9 @@ def plan_chain(chain: Chain, *, backend: str = "auto", mxu_min: int = 128,
             continue
         k_shape = (tuple(chain.shape_of(node.kernel))
                    if node.kernel is not None else None)
-        tag, fn = dispatch_gconv(node, k_shape, backend, mxu_min)
+        tag, fn = dispatch_gconv(
+            node, k_shape, backend, mxu_min,
+            after_pool=is_max_pool(chain.nodes.get(node.input)))
         dispatch[name] = tag
         steps.append(Step(name, tag, _gconv_step(node, fn)))
     ins = ";".join(f"{n}:{'x'.join(map(str, i.shape))}:{i.dtype}"

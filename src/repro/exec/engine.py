@@ -50,7 +50,8 @@ from ..obs import compiles
 from ..obs.metrics import Metrics
 from .batch import BucketedCache, batch_bucket, pad_leading, unpad_leading
 from .dispatch import Plan, plan_chain
-from .lowering import dim_classes, is_depthwise, match_conv, window_fold
+from .lowering import (dim_classes, is_depthwise, is_grouped, match_conv,
+                       window_fold)
 from .partition import partition_chain
 
 
@@ -104,8 +105,9 @@ class CompiledChain:
         # ``tracer`` while it is enabled; ``metrics`` holds each program's
         # build counters and the phase seconds of timed calls, and, counted
         # once here, the reduce steps by how their window dims fold
-        # (lowering.window_fold), the depthwise convs by backend and the
-        # spatial-kernel convs whose output width the kernel pads
+        # (lowering.window_fold), the depthwise and the grouped convs by
+        # backend and the spatial-kernel convs whose output width the
+        # kernel pads
         self.tracer = tracer
         self.metrics = Metrics()
         folds = [window_fold(chain.nodes[s.name]) for s in self.steps
@@ -119,6 +121,11 @@ class CompiledChain:
                                          "conv:lax"}):
             self.metrics.counter("engine_depthwise_steps",
                                  backend=backend).inc(dw.count(backend))
+        grouped = [s.backend for s in self.steps
+                   if _is_grouped(chain, s.name)]
+        for backend in sorted(set(grouped) | {"conv:pallas", "conv:lax"}):
+            self.metrics.counter("engine_grouped_conv_steps",
+                                 backend=backend).inc(grouped.count(backend))
         self.metrics.counter("engine_spatial_width_padded_steps").inc(
             sum(_spatial_width_padded(chain, s.name) for s in self.steps
                 if s.backend == "conv:pallas"))
@@ -438,6 +445,12 @@ def _is_depthwise(chain: Chain, name: str) -> bool:
     return plan is not None and is_depthwise(chain.nodes[name], plan)
 
 
+def _is_grouped(chain: Chain, name: str) -> bool:
+    """Is the step ``name`` a grouped conv (``lowering.is_grouped``)?"""
+    plan = _conv_plan(chain, name)
+    return plan is not None and is_grouped(chain.nodes[name], plan)
+
+
 def _spatial_width_padded(chain: Chain, name: str) -> bool:
     """Does ``gconv_spatial`` compute the ``conv:pallas`` step ``name``
     wider than its output (``gconv_spatial.block_width``)?"""
@@ -483,7 +496,9 @@ def compile_chain(chain: Chain, mesh=None, tracer=None,
     fold in one ``lax.reduce_window`` or as shifted slices
     (``lowering.window_fold``); ``engine_depthwise_steps`` counts the
     depthwise conv steps (``lowering.is_depthwise``) by ``backend``
-    (``dwconv:pallas-vpu`` where the kernel takes them, else ``conv:lax``).
+    (``dwconv:pallas-vpu`` where the kernel takes them, else ``conv:lax``),
+    and ``engine_grouped_conv_steps`` the grouped convs with more than one
+    channel a group, by ``backend`` (``conv:pallas``, ``conv:lax``).
     ``engine_args_converted`` counts the values a call had to convert
     (NumPy arrays, Python scalars, lists); device arrays count nothing.
     ``compile_chain``'s own phases are annotated ``compile.partition``,

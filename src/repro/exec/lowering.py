@@ -258,6 +258,15 @@ def is_depthwise(node: GConv, plan) -> bool:
     return d.nks == 1 and d.nop == 1
 
 
+def is_grouped(node: GConv, plan) -> bool:
+    """A conv (``match_conv``'s plan) whose channel dim has ``Ng > 1``
+    groups of ``Nks > 1`` input channels: a grouped conv that contracts
+    over channels, which :func:`lower_conv_pallas` runs on the kernel's
+    group axis (one channel a group is :func:`is_depthwise`)."""
+    d = node.dims[plan[0]]
+    return d.ng > 1 and d.nks > 1
+
+
 def lower_conv(node: GConv, plan) -> Callable:
     """``lax.conv_general_dilated`` at XLA's default precision, except a
     depthwise conv (:func:`is_depthwise`), which runs at
@@ -308,18 +317,34 @@ def lower_conv(node: GConv, plan) -> Callable:
     return fn
 
 
-def lower_conv_pallas(node: GConv, plan) -> Optional[Callable]:
-    """NHWC Pallas spatial kernel for the plain 2-D case, at the kernel's
-    own ``BLOCK_O``; None when the geometry doesn't fit, and the caller
-    dispatches to :func:`lower_conv`.
+def lower_conv_pallas(node: GConv, plan, *,
+                      after_pool: bool = False) -> Optional[Callable]:
+    """NHWC Pallas spatial kernel for the plain 2-D case, grouped or not,
+    at the kernel's own ``BLOCK_O``; None when the geometry doesn't fit,
+    and the caller dispatches to :func:`lower_conv`.
 
     Eligibility, checked statically on every backend so the plan is the
     same in interpret mode and on the chip:
 
-      * groups == 1, square stride and padding, symmetric padding (the
-        kernel's signature);
+      * square stride and padding, symmetric padding (the kernel's
+        signature);
+      * more than one input channel a group: ``Ng == 1``, or ``Ng > 1``
+        with ``Nks > 1`` (a depthwise conv, one channel a group, is
+        ``lower_depthwise_pallas``'s);
+      * a grouped conv, which runs on the kernel's group axis, only
+        where a max pool produces its input (``after_pool``). On a TPU
+        v5e at AlexNet b32 the kernel was slower than XLA's grouped conv
+        at each of the three (conv2 0.239 against 0.195 ms a call,
+        conv4 0.089 against 0.066, conv5 0.048 against 0.033). The plan
+        gains only where XLA's grouped conv makes the ops that feed it
+        pay a relayout: with conv2 on XLA, the LRN and max pool in front
+        of it took 0.331 ms a call, a 0.125-ms copy among them; with
+        conv2 on the kernel XLA fuses the group split into the pool,
+        which took 0.131 ms. conv4 and conv5, fed by conv3 and conv4,
+        have no such op to spare;
       * ``kernels.gconv_spatial.mosaic_refusal`` is None. Each of its
-        rules is a refusal seen compiling for a TPU v5e:
+        rules is a refusal seen compiling for a TPU v5e, and the block
+        rules count one group's block:
 
         - stride != 1 — full-width AlexNet conv1 (32x227x227x3, 11x11,
           stride 4): "'vector.extract_strided_slice' op expected strides
@@ -340,15 +365,19 @@ def lower_conv_pallas(node: GConv, plan) -> Optional[Callable]:
     ch, windows, batch = plan
     dims = node.dims
     dch = dims[ch]
-    if len(windows) != 2 or dch.ng != 1:
+    groups = dch.ng
+    if len(windows) != 2 or (groups > 1 and not is_grouped(node, plan)):
         return None
     dh, dw = dims[windows[0]], dims[windows[1]]
     if (dh.stride, dh.pad) != (dw.stride, dw.pad):
         return None
     if dh.padr != dh.pad or dw.padr != dw.pad:
         return None
-    if mosaic_refusal(dh.nips, dw.nips, dch.in_size, dh.nks, dw.nks, dch.nop,
-                      stride=dh.stride, pad=dh.pad):
+    if groups > 1 and not after_pool:
+        return None
+    if mosaic_refusal(dh.nips, dw.nips, dch.in_size, dh.nks, dw.nks,
+                      groups * dch.nop, stride=dh.stride, pad=dh.pad,
+                      groups=groups):
         return None
 
     from ..kernels.gconv_spatial import gconv_spatial
@@ -364,11 +393,17 @@ def lower_conv_pallas(node: GConv, plan) -> Optional[Callable]:
         xb = xb.reshape(nb, dch.in_size, dh.nips, dw.nips)
         xb = jnp.transpose(xb, (0, 2, 3, 1))                 # NHWC
         kb = jnp.transpose(k.astype(ct), [ch] + windows + batch)
-        kb = kb.reshape(dch.nop, dch.nks, dh.nks, dw.nks)    # OIHW
-        kb = jnp.transpose(kb, (2, 3, 1, 0))                 # HWIO
-        y = gconv_spatial(xb, kb, stride=dh.stride, pad=dh.pad)
+        if groups == 1:
+            kb = kb.reshape(dch.nop, dch.nks, dh.nks, dw.nks)       # OIHW
+            kb = jnp.transpose(kb, (2, 3, 1, 0))                    # HWIO
+        else:
+            kb = kb.reshape(groups, dch.nop, dch.nks, dh.nks, dw.nks)
+            kb = jnp.transpose(kb, (0, 3, 4, 2, 1))                 # GHWIO
+        y = gconv_spatial(xb, kb, groups=groups, stride=dh.stride,
+                          pad=dh.pad)
         y = jnp.transpose(y, (0, 3, 1, 2))
-        y = y.reshape(tuple(b_sizes) + (dch.nop, dh.nopc, dw.nopc))
+        y = y.reshape(tuple(b_sizes) + (groups * dch.nop, dh.nopc,
+                                        dw.nopc))
         y = jnp.transpose(y, np.argsort(perm)).reshape(node.out_shape)
         return _finish(node, y, lookup)
 

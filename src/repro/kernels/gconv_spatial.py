@@ -7,12 +7,13 @@ resident block, feeding the MXU with (spatial-positions x C) @ (C x O)
 contractions. HBM traffic is exactly the unique input footprint — the
 Table-3 input-movement formula, not the im2col-replicated one.
 
-Blocking: grid (B, O-tiles). Each step holds one padded input image
-(H+2p, W+2p+OWp-OW, C) and one kernel slice (KH, KW, C, bo) in VMEM and
-produces the (OH, OW, bo) output block. The static KH x KW Python loop
-unrolls into MXU dots over the same VMEM block — this is the Eyeriss
-overlap-reuse primitive (paper Fig. 8) re-derived for a vector/matrix
-memory hierarchy.
+Blocking: grid (B*G, O-tiles), G = 1 for an ungrouped conv. Each step
+holds one padded input image (H+2p, W+2p+OWp-OW, C) and one kernel slice
+(KH, KW, C, bo) in VMEM and produces the (OH, OW, bo) output block; in a
+grouped conv C and the O-tiles are one group's. The static KH x KW
+Python loop unrolls into MXU dots over the same VMEM block — this is the
+Eyeriss overlap-reuse primitive (paper Fig. 8) re-derived for a
+vector/matrix memory hierarchy.
 
 Each tap reads its (OH, OWp, C) window straight from the input ref, where
 OWp = :func:`block_width` (OW) is the output width rounded up to the
@@ -23,6 +24,17 @@ every tap's window. The input's W axis takes OWp-OW extra zero columns on
 the right, in the same ``jnp.pad`` as the conv padding; they feed only the
 discarded outputs OW..OWp-1, and the store keeps the first OW columns, so
 every kept output sums the same products in the same order.
+
+A grouped conv (``groups=G``: each group's O/G outputs read only its
+own C/G inputs) runs the same body, one grid row per image and group.
+C/G need not fill the 128 lanes (AlexNet's 48 and 192), so no BlockSpec
+could slice a group's channels out of (B, H, W, C): the wrapper splits
+the input into (B*G, H, W, C/G), group-major, before the same
+``jnp.pad``, each block
+takes one group's whole channel axis, and the output is joined back from
+(B*G, OH, OW, O/G) where it is sliced. The same ``pallas_call`` runs
+under the jitted name ``_gconv_grouped``, so that a device trace tells
+the grouped convs from the ungrouped ones (``_gconv_spatial``).
 
 Nothing splits a feature map into halo tiles: the whole padded image is one
 block, so Mosaic compiles the kernel only for stride 1 and for geometries
@@ -73,15 +85,28 @@ def block_width(ow: int) -> int:
     return round_up(ow, SUBLANE)
 
 
+def out_block(o: int, groups: int, block_o: int = BLOCK_O) -> int:
+    """Output channels of one grid step, for ``o`` outputs a group:
+    ``block_o``, or all of ``o`` where it is smaller. A grouped conv whose
+    ``o`` is no multiple of ``block_o`` takes its whole group in one block
+    (AlexNet conv4: 192), not a zero-padded second one."""
+    if groups > 1 and o % block_o:
+        return o
+    return min(block_o, o)
+
+
 def mosaic_refusal(h: int, w: int, c: int, kh: int, kw: int, o: int, *,
-                   stride: int, pad: int,
-                   block_o: int = BLOCK_O) -> Optional[str]:
-    """Why Mosaic refuses ``gconv_spatial`` at this geometry, or None when
-    it compiles. Each rule is a refusal seen compiling for a TPU v5e."""
+                   stride: int, pad: int, block_o: int = BLOCK_O,
+                   groups: int = 1) -> Optional[str]:
+    """Why Mosaic refuses ``gconv_spatial`` at this geometry (``c`` and
+    ``o`` over all ``groups``), or None when it compiles. Each rule is a
+    refusal seen compiling for a TPU v5e; the block rules count one
+    group's block."""
     if stride != 1:
         return (f"stride {stride}: strided window slices are refused "
                 f"('vector.extract_strided_slice' strides must be 1)")
-    bo = min(block_o, o)
+    c, o = c // groups, o // groups
+    bo = out_block(o, groups, block_o)
     if bo != o and bo % O_ALIGN:
         return (f"block_o {bo} < O={o} is not a multiple of {O_ALIGN} "
                 f"(block shape must be (8, 128)-divisible or whole)")
@@ -114,48 +139,82 @@ def _kernel(x_ref, w_ref, o_ref, *, kh: int, kw: int, stride: int,
     o_ref[0] = acc.reshape(oh, owp, -1)[:, :ow]
 
 
-def gconv_spatial(x: jax.Array, w: jax.Array, *, stride: int = 1,
-                  pad: int = 0, block_o: int = BLOCK_O,
+def gconv_spatial(x: jax.Array, w: jax.Array, *, groups: int = 1,
+                  stride: int = 1, pad: int = 0, block_o: int = BLOCK_O,
                   interpret: Optional[bool] = None) -> jax.Array:
     """NHWC conv: x (B, H, W, C), w (KH, KW, C, O) -> (B, OH, OW, O) f32.
 
+    At ``groups=G > 1``, w is (G, KH, KW, C/G, O/G), and the channels of x
+    and of the output are group-major: group g reads x's channels
+    g*C/G.. and writes outputs g*O/G.. (``feature_group_count=G``).
+
     ``interpret`` resolves outside the jit boundary so the
     ``REPRO_FORCE_INTERPRET`` override keys the jit cache."""
+    if w.ndim != (4 if groups == 1 else 5) or (groups > 1
+                                                and w.shape[0] != groups):
+        raise ValueError(f"groups={groups} takes w as (KH, KW, C, O) at 1, "
+                         f"else (G, KH, KW, C/G, O/G); got {w.shape}")
     if interpret is None:
         interpret = use_interpret()
-    return _gconv_spatial(x, w, stride=stride, pad=pad, block_o=block_o,
-                          interpret=bool(interpret))
+    call = _gconv_spatial if groups == 1 else _gconv_grouped
+    return call(x, w, stride=stride, pad=pad, block_o=block_o,
+                interpret=bool(interpret))
 
 
-@functools.partial(
-    jax.jit, static_argnames=("stride", "pad", "block_o", "interpret"))
-def _gconv_spatial(x, w, *, stride, pad, block_o, interpret):
+def _call(x, w, *, stride, pad, block_o, interpret):
+    """The kernel over grid (B*G, O-tiles): image b, group g is row
+    b*G + g of a group-major (B*G, H, W, C/G) input. A 4-D w is one
+    group, and its input is x as it is."""
     B, H, W, C = x.shape
-    KH, KW, C2, O = w.shape
-    assert C == C2
+    G = w.shape[0] if w.ndim == 5 else 1
+    KH, KW, Cg, O = w.shape[-4:]
+    assert C == G * Cg
     oh = (H + 2 * pad - KH) // stride + 1
     ow = (W + 2 * pad - KW) // stride + 1
     owp = block_width(ow)
     extra = (owp - ow) * stride          # zero columns for outputs ow..owp-1
+    if G > 1:
+        x = jnp.transpose(x.reshape(B, H, W, G, Cg), (0, 3, 1, 2, 4))
+        x = x.reshape(B * G, H, W, Cg)
     if pad or extra:
         x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad + extra), (0, 0)))
     Hp, Wp = H + 2 * pad, W + 2 * pad + extra
-    bo = min(block_o, O)
+    bo = out_block(O, G, block_o)
     Op = cdiv(O, bo) * bo
+    w = w.reshape(G * KH, KW, Cg, O)     # group g: rows g*KH..
     if Op != O:          # boundary blocks must be well-defined: zero-pad O
         w = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, Op - O)))
-    grid = (B, Op // bo)
 
     out = pl.pallas_call(
         functools.partial(_kernel, kh=KH, kw=KW, stride=stride, oh=oh, ow=ow,
                           owp=owp),
-        grid=grid,
+        grid=(B * G, Op // bo),
         in_specs=[
-            pl.BlockSpec((1, Hp, Wp, C), lambda b, o: (b, 0, 0, 0)),
-            pl.BlockSpec((KH, KW, C, bo), lambda b, o: (0, 0, 0, o)),
+            pl.BlockSpec((1, Hp, Wp, Cg), lambda r, o: (r, 0, 0, 0)),
+            pl.BlockSpec((KH, KW, Cg, bo),
+                         lambda r, o: (r % G if G > 1 else 0, 0, 0, o)),
         ],
-        out_specs=pl.BlockSpec((1, oh, ow, bo), lambda b, o: (b, 0, 0, o)),
-        out_shape=jax.ShapeDtypeStruct((B, oh, ow, Op), jnp.float32),
+        out_specs=pl.BlockSpec((1, oh, ow, bo), lambda r, o: (r, 0, 0, o)),
+        out_shape=jax.ShapeDtypeStruct((B * G, oh, ow, Op), jnp.float32),
         interpret=interpret,
     )(x, w)
-    return out[..., :O]
+    out = out[..., :O]
+    if G > 1:
+        out = jnp.transpose(out.reshape(B, G, oh, ow, O), (0, 2, 3, 1, 4))
+    return out.reshape(B, oh, ow, G * O)
+
+
+# two jitted names for one kernel: a device trace names a kernel's custom
+# call after the jitted function that launches it
+@functools.partial(
+    jax.jit, static_argnames=("stride", "pad", "block_o", "interpret"))
+def _gconv_spatial(x, w, *, stride, pad, block_o, interpret):
+    return _call(x, w, stride=stride, pad=pad, block_o=block_o,
+                 interpret=interpret)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("stride", "pad", "block_o", "interpret"))
+def _gconv_grouped(x, w, *, stride, pad, block_o, interpret):
+    return _call(x, w, stride=stride, pad=pad, block_o=block_o,
+                 interpret=interpret)

@@ -488,7 +488,7 @@ def test_prefer_pallas_accepts_aligned_m(monkeypatch):
 # lowerings' refusal rules) that moves a step shows here and must update
 # these numbers on purpose.
 CHIP_PLAN = {
-    "AN": {"conv:lax": 4, "conv:pallas": 1, "fused": 14, "matmul:pallas": 3,
+    "AN": {"conv:lax": 3, "conv:pallas": 2, "fused": 14, "matmul:pallas": 3,
            "movement": 1, "reduce": 5, "segment:softmax": 1},
     "GLN": {"concat": 9, "conv:lax": 1, "conv:pallas": 19,
             "elementwise": 37, "fused": 26, "matmul:jnp": 21,
@@ -518,13 +518,56 @@ def test_chip_rule_plan(name, monkeypatch):
     assert eng.backend_histogram() == CHIP_PLAN[name]
 
 
-@pytest.mark.parametrize("name,want", [("GLN", 18), ("MN", 0)])
+@pytest.mark.parametrize("name,want", [("GLN", 18), ("MN", 0), ("AN", 2)])
 def test_engine_counts_spatial_width_padded_steps(name, want, monkeypatch):
     """At the chip plan every GoogLeNet ``conv:pallas`` step but conv2
     (output width 56) has an output width off the sublane tile (28, 14 or
-    7) that ``gconv_spatial`` pads; MobileNet plans no such step."""
+    7) that ``gconv_spatial`` pads; MobileNet plans no such step; both of
+    AlexNet's (conv2, 27 wide, and conv3, 13 wide) are padded."""
     monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
     eng = compile_chain(cnn.build(name), backend="auto", lint="off")
     got = eng.metrics.to_dict()["metrics"][
         "engine_spatial_width_padded_steps"]["series"][0]["value"]
     assert got == want
+
+
+@pytest.mark.parametrize("name,backend,want", [
+    ("AN", "auto", {"conv:pallas": 1, "conv:lax": 2}),
+    ("AN", "jnp", {"conv:pallas": 0, "conv:lax": 3}),
+    ("GLN", "auto", {"conv:pallas": 0, "conv:lax": 0}),
+    ("MN", "auto", {"conv:pallas": 0, "conv:lax": 0}),
+])
+def test_engine_counts_grouped_conv_steps_by_backend(name, backend, want,
+                                                     monkeypatch):
+    """Of AlexNet's two-group convs, conv2 (after a max pool) runs on
+    ``gconv_spatial``'s group axis at the chip plan and conv4 and conv5 on
+    XLA (``lowering.lower_conv_pallas``); MobileNet's depthwise convs (one
+    channel a group) are not counted."""
+    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
+    eng = compile_chain(cnn.build(name), backend=backend, lint="off")
+    fam = eng.metrics.to_dict()["metrics"]["engine_grouped_conv_steps"]
+    assert {s["labels"]["backend"]: s["value"]
+            for s in fam["series"]} == want
+
+
+@pytest.mark.parametrize("name,pool,kernel", [
+    ("conv2", True, True), ("conv4", False, False), ("conv5", False, False),
+    ("conv3", True, True),
+])
+def test_grouped_convs_take_the_kernel_after_a_max_pool(name, pool, kernel):
+    """A grouped conv takes ``gconv_spatial`` only where a max pool feeds
+    it (AlexNet's conv2; conv4 and conv5 follow a conv and stay on XLA),
+    and only that rule refuses conv4 and conv5: told a pool feeds them,
+    the kernel takes them. Ungrouped conv3 is no grouped conv."""
+    from repro.exec import lowering
+    from repro.exec.dispatch import is_max_pool
+
+    chain = cnn.build("AN")
+    node = chain.nodes[name]
+    plan = lowering.match_conv(node, lowering.dim_classes(node),
+                               tuple(chain.shape_of(node.kernel)))
+    assert is_max_pool(chain.nodes[node.input]) == pool
+    assert lowering.is_grouped(node, plan) == (name != "conv3")
+    assert (lowering.lower_conv_pallas(node, plan, after_pool=pool)
+            is not None) == kernel
+    assert lowering.lower_conv_pallas(node, plan, after_pool=True) is not None

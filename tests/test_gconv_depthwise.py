@@ -156,9 +156,9 @@ UNCHANGED = {
     ("GLN", "pallas"): {"concat": 9, "conv:lax": 1, "conv:pallas": 19,
                         "elementwise": 37, "matmul:pallas": 38,
                         "movement": 1, "reduce": 16, "segment:softmax": 1},
-    ("AN", "auto"): {"conv:lax": 4, "conv:pallas": 1, "matmul:pallas": 3,
+    ("AN", "auto"): {"conv:lax": 3, "conv:pallas": 2, "matmul:pallas": 3,
                      "movement": 1, "reduce": 5, "segment:softmax": 1},
-    ("AN", "pallas"): {"conv:lax": 4, "conv:pallas": 1, "matmul:pallas": 3,
+    ("AN", "pallas"): {"conv:lax": 3, "conv:pallas": 2, "matmul:pallas": 3,
                        "movement": 1, "reduce": 5, "segment:softmax": 1},
 }
 

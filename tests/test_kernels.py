@@ -98,6 +98,42 @@ def test_gconv_spatial_sweep(b, h, c, o, kk, stride, pad, dtype):
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
 
 
+# (B, H, G, C/G, O/G, K, pad, block_o): two groups of channels that fill
+# no lane tile (AlexNet's 48 and 192, scaled down), output widths 7 and
+# 13, pads 1 and 2; O/G 12 at block_o 8 takes a whole-group block, O/G 16
+# two blocks of 8; and three groups
+@pytest.mark.parametrize("b,h,g,cg,og,kk,pad,block_o", [
+    (2, 7, 2, 6, 12, 3, 1, 128), (1, 13, 2, 12, 20, 3, 1, 128),
+    (2, 9, 2, 6, 16, 5, 2, 8), (1, 13, 2, 6, 12, 5, 2, 8),
+    (1, 7, 3, 4, 8, 3, 1, 128),
+])
+def test_gconv_spatial_grouped_matches_feature_group_count(b, h, g, cg, og,
+                                                           kk, pad, block_o):
+    x = rnd(40, (b, h, h, g * cg), jnp.float32)
+    w = rnd(41, (g, kk, kk, cg, og), jnp.float32)
+    got = gconv_spatial(x, w, groups=g, pad=pad, block_o=block_o,
+                        interpret=True)
+    # (G, KH, KW, C/G, O/G) -> HWIO with group-major O
+    w_lax = jnp.transpose(w, (1, 2, 3, 0, 4)).reshape(kk, kk, cg, g * og)
+    want = jax.lax.conv_general_dilated(
+        x, w_lax, (1, 1), [(pad, pad)] * 2,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), feature_group_count=g,
+        precision=jax.lax.Precision.HIGHEST)
+    assert got.shape == want.shape == (b, h, h, g * og)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("groups,w_shape", [(2, (3, 3, 4, 8)),
+                                            (1, (2, 3, 3, 4, 8)),
+                                            (3, (2, 3, 3, 4, 8))])
+def test_gconv_spatial_refuses_weights_that_do_not_match_groups(groups,
+                                                                w_shape):
+    x = rnd(42, (1, 7, 7, 8), jnp.float32)
+    with pytest.raises(ValueError, match="groups="):
+        gconv_spatial(x, rnd(43, w_shape, jnp.float32), groups=groups,
+                      interpret=True)
+
+
 # ---------------------------------------------------------------------------
 # fused norm
 # ---------------------------------------------------------------------------

@@ -98,6 +98,24 @@ def test_gconv_spatial_compiles(one_chip, geom):
              one_chip, (B, H, W, C), (K, K, C, O))
 
 
+# (B, H, W, C, K, O, pad) over both groups of AlexNet's two-group convs
+# at b32: C/G = 48 and 192 fill no lane tile, conv4's O/G = 192 takes a
+# whole-group output block
+GROUPED = {"AN.conv2": (32, 27, 27, 96, 5, 256, 2),
+           "AN.conv4": (32, 13, 13, 384, 3, 384, 1),
+           "AN.conv5": (32, 13, 13, 384, 3, 256, 1)}
+
+
+@pytest.mark.parametrize("geom", list(GROUPED.values()), ids=list(GROUPED))
+def test_gconv_spatial_grouped_compiles(one_chip, geom):
+    B, H, W, C, K, O, pad = geom
+    assert mosaic_refusal(H, W, C, K, K, O, stride=1, pad=pad,
+                          groups=2) is None
+    _compile(functools.partial(gconv_spatial, groups=2, pad=pad,
+                               interpret=False),
+             one_chip, (B, H, W, C), (2, K, K, C // 2, O // 2))
+
+
 def test_vmem_rule_counts_the_widened_input_block():
     """A 14x14 3x3 conv (pad 1) computes 16 output columns, so its input
     block is 18 wide, not 16. At C = 704 that widening alone takes the
@@ -155,11 +173,29 @@ def test_refused_geometry_fails_mosaic_and_the_rule(one_chip, case):
                  one_chip, (B, H, W, C), (K, K, C, O))
 
 
+def test_refused_grouped_geometry_fails_mosaic_and_the_rule(one_chip):
+    """The VMEM rule counts one group's blocks: two groups of 64 channels
+    on a 112x112 map hold the blocks of the refused ungrouped
+    112x112x64 conv."""
+    B, H, W, C, K, O = 32, 112, 112, 128, 3, 128
+    assert "VMEM" in mosaic_refusal(H, W, C, K, K, O, stride=1, pad=1,
+                                    groups=2)
+    assert mosaic_refusal(H, W, C // 2, K, K, O // 2, stride=1, pad=1) \
+        == mosaic_refusal(H, W, C, K, K, O, stride=1, pad=1, groups=2)
+    with pytest.raises(Exception):
+        _compile(functools.partial(gconv_spatial, groups=2, pad=1,
+                                   interpret=False),
+                 one_chip, (B, H, W, C), (2, K, K, C // 2, O // 2))
+
+
 def test_alexnet_conv1_plans_lax_under_pallas():
     """Full-width AN under ``backend="pallas"``: conv1 (stride 4) takes
-    ``conv:lax`` through the eligibility rule, while the stride-1
-    ungrouped conv3 keeps the Pallas kernel."""
+    ``conv:lax`` through the eligibility rule, and so do the two-group
+    conv4 and conv5, which no max pool feeds, while ungrouped conv3 and
+    two-group conv2, after a max pool, keep the Pallas kernel."""
     eng = compile_chain(cnn.build("AN"), backend="pallas")
-    assert eng.dispatch["conv1"] == "conv:lax"
-    assert eng.dispatch["conv3"] == "conv:pallas"
+    for name in ("conv1", "conv4", "conv5"):
+        assert eng.dispatch[name] == "conv:lax"
+    for name in ("conv2", "conv3"):
+        assert eng.dispatch[name] == "conv:pallas"
     assert eng.dispatch["fc6"] == "matmul:pallas"
